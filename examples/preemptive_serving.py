@@ -20,6 +20,7 @@ import jax.numpy as jnp
 
 from repro.configs import get_smoke_config
 from repro.core.task import Priority
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as M
 from repro.serving.cost_model import measure_cost_model
 from repro.serving.engine import (
@@ -40,11 +41,12 @@ def main() -> None:
     ap.add_argument("--lp-tokens", type=int, default=24)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_smoke_config(args.arch)
     params = M.init_params(cfg, jax.random.PRNGKey(0))
     print(f"[setup] measuring step costs for reduced {args.arch} "
           "(the paper's offline benchmark phase)")
-    cost = measure_cost_model(cfg, reps=3)
+    cost = measure_cost_model(cfg, prompt_len=16, cache_len=256, reps=3)
     net = engine_network_config(cost, args.lp_tokens)
 
     eng = PreemptiveServingEngine(

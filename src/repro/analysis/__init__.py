@@ -20,9 +20,9 @@ Rule families (DESIGN.md §15 is the catalog):
   — wall-clock reads, unseeded RNG, and unordered set iteration inside the
   ``core/`` + ``sim/`` decision paths.
 * ``pallas-index`` / ``jax-free-boundary`` — bare-int ``pl.load`` /
-  ``pl.store`` / ``pl.swap`` indices (the interpret-mode discharge bug
-  fixed in PR 3) and module-level jax imports in the streaming-path
-  modules PR 7 deliberately kept jax-free.
+  ``pl.store`` / ``pl.swap`` indices (rejected in interpret mode by older
+  JAX; jax 0.9.0 has no such functions) and module-level jax imports in
+  the streaming-path modules PR 7 deliberately kept jax-free.
 
 Suppression is explicit and line-scoped: ``# replint: disable=<rule>`` on
 the flagged line, or an entry in the committed baseline file

@@ -1,12 +1,11 @@
 """Kernel and import-boundary rules.
 
 * ``pallas-index`` — a bare Python int as a TOP-LEVEL element of a
-  ``pl.load`` / ``pl.store`` / ``pl.swap`` index tuple.  This JAX
-  version's interpret-mode discharge rule rejects it (``'int' object has
-  no attribute 'shape'``) — the bug that broke all 18 flash-attention
-  sweeps until PR 3 rewrote the index as ``pl.ds(0, 1)`` + squeeze.
-  Ints nested inside ``pl.ds(0, 1)`` or arithmetic (``s * bk``) are fine;
-  only a naked integer element trips the discharge rule.
+  ``pl.load`` / ``pl.store`` / ``pl.swap`` index tuple, which older JAX
+  releases rejected in interpret mode (``'int' object has no attribute
+  'shape'``).  The installed jax 0.9.0 has none of these functions;
+  kernels index refs directly.  Ints nested inside ``pl.ds(0, 1)`` or
+  arithmetic (``s * bk``) are not flagged.
 * ``jax-free-boundary`` — module-level jax imports in the modules the
   streaming path deliberately keeps jax-free (``core/``, ``sim/``,
   ``serving/stream.py`` and the lazy ``serving/__init__.py``): a single
@@ -43,7 +42,7 @@ def _bare_int(node: ast.AST) -> bool:
 class PallasIndexRule(Rule):
     name = "pallas-index"
     description = ("bare Python int inside a pl.load/pl.store/pl.swap "
-                   "index tuple (interpret-mode discharge rejects it)")
+                   "index tuple (older JAX rejects it in interpret mode)")
 
     def check(self, mod: Module) -> Iterator[Finding]:
         aliases = {name for name, origin in mod.imports.items()
@@ -69,9 +68,9 @@ class PallasIndexRule(Rule):
                 yield Finding(
                     self.name, mod.rel, node.lineno, node.col_offset,
                     f"bare Python int ({rendered}) as a top-level element "
-                    f"of a {func.value.id}.{func.attr} index tuple — the "
-                    "interpret-mode discharge rule rejects it; use "
-                    "pl.ds(i, 1) + squeeze instead",
+                    f"of a {func.value.id}.{func.attr} index tuple — "
+                    "older JAX rejects it in interpret mode, and jax 0.9 "
+                    "has no such function; index the ref directly",
                     mod.qualname(node.lineno))
 
 

@@ -70,7 +70,7 @@ def decode_attention(
     *,
     window: int = 0,
     block_s: int = 128,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     b, h, d = q.shape
     s, kv = k_cache.shape[1], k_cache.shape[2]

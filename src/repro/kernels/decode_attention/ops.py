@@ -20,14 +20,17 @@ def cached_decode_attention(
     *,
     window: int = 0,
     use_pallas: bool = True,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     q1 = q[:, 0]
+    if not use_pallas:
+        return decode_attention_ref(q1, cache_k, cache_v, positions, pos,
+                                    window=window)[:, None]
     s = cache_k.shape[1]
-    if use_pallas and s % 128 == 0:
-        o = decode_attention(q1, cache_k, cache_v, positions, pos,
-                             window=window, block_s=128, interpret=interpret)
-    else:
-        o = decode_attention_ref(q1, cache_k, cache_v, positions, pos,
-                                 window=window)
+    if s % 128:
+        raise ValueError(
+            f"decode attention needs a cache length divisible by its "
+            f"128-slot block, got S={s}; pass use_pallas=False")
+    o = decode_attention(q1, cache_k, cache_v, positions, pos,
+                         window=window, block_s=128, interpret=interpret)
     return o[:, None]

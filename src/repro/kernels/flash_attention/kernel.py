@@ -31,13 +31,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, bk: int, causal: bool,
 
     def body(s, carry):
         m, l, acc = carry
-        # Leading block axis indexed with pl.ds(0, 1) + squeeze, NOT a bare
-        # Python int: interpret-mode discharge of pl.load rejects scalar int
-        # indices ('int' object has no attribute 'shape').
-        k = pl.load(k_ref, (pl.ds(0, 1), pl.ds(s * bk, bk), slice(None))
-                    )[0].astype(jnp.float32)       # [bk, D]
-        v = pl.load(v_ref, (pl.ds(0, 1), pl.ds(s * bk, bk), slice(None))
-                    )[0].astype(jnp.float32)
+        k = k_ref[0, pl.ds(s * bk, bk), :].astype(jnp.float32)   # [bk, D]
+        v = v_ref[0, pl.ds(s * bk, bk), :].astype(jnp.float32)
         scores = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
         k_pos = s * bk + jax.lax.iota(jnp.int32, bk)
         mask = jnp.ones((bq, bk), bool)
@@ -72,7 +67,7 @@ def flash_attention(
     window: int = 0,
     bq: int = 128,
     bk: int = 128,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     b, h, t, d = q.shape
     assert t % bq == 0 and t % bk == 0, "T must divide into blocks"

@@ -52,7 +52,7 @@ def halo_conv_block_tiles(
     tile_h: int,
     tile_w: int,
     leaky: float = 0.1,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     n_layers = len(weights)
     r = n_layers                          # 3x3 conv: halo ring of 1 per layer

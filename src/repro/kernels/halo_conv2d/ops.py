@@ -40,7 +40,7 @@ def halo_conv_block(
     *,
     tiles: tuple[int, int] = (2, 2),
     leaky: float = 0.1,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     n, h, w, _ = x.shape
     n_th, n_tw = tiles

@@ -74,7 +74,7 @@ def slstm_scan(
     b: jax.Array,              # [4, H, dh] bias
     *,
     block_t: int = 128,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     """Returns hidden states hs [B, T, H, dh] (float32)."""
     bsz, t, four, h, dh = wx.shape

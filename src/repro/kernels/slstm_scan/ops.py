@@ -17,7 +17,7 @@ def slstm_hidden_states(
     *,
     use_pallas: bool = True,
     block_t: int = 128,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     if use_pallas:
         return slstm_scan(wx, r, b, block_t=block_t, interpret=interpret)

@@ -103,7 +103,9 @@ def measure_cost_model(
     if len(set(degrees)) != len(degrees):
         raise ValueError(f"duplicate parallel degrees in {degrees}")
     key = key if key is not None else jax.random.PRNGKey(0)
-    params = M.init_params(cfg, key)
+    # One init program: op-by-op init of a full-width model spends most of
+    # a minute compiling its many small ops on a TPU.
+    params = jax.jit(M.init_params, static_argnums=0)(cfg, key)
     tokens = jax.random.randint(key, (batch, prompt_len), 0, cfg.vocab_size)
     batch_d = {"tokens": tokens}
     if cfg.modality_embed_dim:
@@ -113,8 +115,10 @@ def measure_cost_model(
 
     pre = jax.jit(make_prefill_step(cfg, cache_len))
     srv = jax.jit(make_serve_step(cfg))
+    # One untimed call of each step, so no compile lands in the timed reps.
     nxt, caches = jax.tree.map(jnp.asarray, pre(params, batch_d))
-    jax.block_until_ready(nxt)
+    pos = jnp.asarray(prompt_len, jnp.int32)
+    jax.block_until_ready(srv(params, caches, nxt[:, None], pos))
 
     def timeit(fn, *a):
         ts = []
@@ -127,7 +131,6 @@ def measure_cost_model(
         return float(np.mean(ts)), float(np.std(ts)), out
 
     p_mean, p_std, _ = timeit(pre, params, batch_d)
-    pos = jnp.asarray(prompt_len, jnp.int32)
     d_mean, d_std, _ = timeit(srv, params, caches, nxt[:, None], pos)
 
     # paper-calibrated parallel efficiency: every doubling of the degree

@@ -1,13 +1,11 @@
-"""Static lint over the Pallas kernel sources: no bare-int ``pl.load``
-indices.
+"""Static lint over the Pallas kernel sources: the ``pallas-index`` rule.
 
-This JAX version's interpret-mode discharge rule for ``pl.load`` rejects a
-bare Python int inside the index tuple (``'int' object has no attribute
-'shape'``) — the bug that broke all 18 flash-attention sweeps until the
-index was rewritten as ``pl.ds(0, 1)`` + squeeze.  The check is the
-``pallas-index`` AST rule from ``repro.analysis`` (which replaced this
-file's original regex/paren-walker), run here per kernel file so the class
-cannot regress silently and the offender is named in the test id.
+The rule flags a bare Python int in a ``pl.load`` / ``pl.store`` /
+``pl.swap`` index tuple, a form older JAX releases rejected in interpret
+mode.  The installed jax 0.9.0 has none of these functions: kernels index
+refs directly (``k_ref[0, pl.ds(s * bk, bk), :]``), and a kernel that
+called them would fail as soon as it is traced.  The rule still runs on
+every kernel file, naming the offender in the test id.
 """
 import textwrap
 from pathlib import Path

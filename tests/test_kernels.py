@@ -1,5 +1,7 @@
-"""Kernel sweeps: shapes x dtypes, assert_allclose against the jnp oracles
-(interpret mode executes the Pallas kernel bodies on CPU)."""
+"""Kernel sweeps: shapes x dtypes, assert_allclose against the jnp oracles.
+
+The kernels default to compiling for the TPU; every call here asks for
+interpret mode, which executes the Pallas kernel bodies on the CPU."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -38,7 +40,7 @@ def test_halo_conv_matches_ref(hw, ch, n_layers, tiles):
     ws = tuple(0.2 * jax.random.normal(jax.random.PRNGKey(i + 1),
                                        (3, 3, ch, ch))
                for i in range(n_layers))
-    y = halo_conv_block(x, ws, tiles=tiles)
+    y = halo_conv_block(x, ws, tiles=tiles, interpret=True)
     yr = conv_block_ref(x, list(ws))
     assert_allclose(np.asarray(y), np.asarray(yr), atol=1e-4, rtol=1e-4)
 
@@ -49,8 +51,8 @@ def test_halo_conv_tiling_invariance():
     x = jax.random.normal(k, (1, 16, 16, 8))
     ws = tuple(0.2 * jax.random.normal(jax.random.PRNGKey(i), (3, 3, 8, 8))
                for i in range(2))
-    y1 = halo_conv_block(x, ws, tiles=(1, 2))   # "2-core"
-    y2 = halo_conv_block(x, ws, tiles=(2, 2))   # "4-core"
+    y1 = halo_conv_block(x, ws, tiles=(1, 2), interpret=True)   # "2-core"
+    y2 = halo_conv_block(x, ws, tiles=(2, 2), interpret=True)   # "4-core"
     assert_allclose(np.asarray(y1), np.asarray(y2), atol=1e-5, rtol=1e-5)
 
 
@@ -72,7 +74,8 @@ def test_flash_attention_sweep(dtype, t, d, bq, bk, causal, window):
     q = jax.random.normal(ks[0], shape, dtype)
     k = jax.random.normal(ks[1], shape, dtype)
     v = jax.random.normal(ks[2], shape, dtype)
-    y = flash_attention(q, k, v, causal=causal, window=window, bq=bq, bk=bk)
+    y = flash_attention(q, k, v, causal=causal, window=window, bq=bq, bk=bk,
+                        interpret=True)
     yr = attention_ref(q, k, v, causal=causal, window=window)
     assert_allclose(np.asarray(y, np.float32), np.asarray(yr, np.float32),
                     atol=tol(dtype), rtol=tol(dtype))
@@ -99,7 +102,8 @@ def test_decode_attention_sweep(dtype, h, kv, s, block_s):
     positions = jnp.where(jnp.arange(s) < fill, jnp.arange(s),
                           -1)[None].repeat(2, 0)
     pos = jnp.int32(fill - 1)
-    y = decode_attention(q, kc, vc, positions, pos, block_s=block_s)
+    y = decode_attention(q, kc, vc, positions, pos, block_s=block_s,
+                         interpret=True)
     yr = decode_attention_ref(q, kc, vc, positions, pos)
     assert_allclose(np.asarray(y, np.float32), np.asarray(yr, np.float32),
                     atol=tol(dtype), rtol=tol(dtype))
@@ -117,9 +121,45 @@ def test_decode_attention_rotating_window():
     slots = pos_abs % s
     positions = jnp.zeros((1, s), jnp.int32).at[0, slots].set(pos_abs)
     pos = jnp.int32(327)
-    y = decode_attention(q, kc, vc, positions, pos, window=100, block_s=64)
+    y = decode_attention(q, kc, vc, positions, pos, window=100, block_s=64,
+                         interpret=True)
     yr = decode_attention_ref(q, kc, vc, positions, pos, window=100)
     assert_allclose(np.asarray(y), np.asarray(yr), atol=2e-5, rtol=2e-5)
+
+
+# --------------------------------------------------------------------------- #
+# model-layout wrappers: kernel or explicit oracle, never a silent fallback   #
+# --------------------------------------------------------------------------- #
+
+
+def _mha(t):
+    from repro.kernels.flash_attention.ops import mha_attention
+    q, k, v = (jax.random.normal(kk, (1, t, 2, 32))
+               for kk in jax.random.split(jax.random.PRNGKey(4), 3))
+    return lambda **kw: mha_attention(q, k, v, **kw)
+
+
+def _cached_decode(s):
+    from repro.kernels.decode_attention.ops import cached_decode_attention
+    ks = jax.random.split(jax.random.PRNGKey(5), 3)
+    q = jax.random.normal(ks[0], (1, 1, 4, 32))
+    kc = jax.random.normal(ks[1], (1, s, 2, 32))
+    vc = jax.random.normal(ks[2], (1, s, 2, 32))
+    positions = jnp.arange(s, dtype=jnp.int32)[None]
+    return lambda **kw: cached_decode_attention(q, kc, vc, positions,
+                                                jnp.int32(s - 1), **kw)
+
+
+@pytest.mark.parametrize("make,good,bad", [(_mha, 128, 200),
+                                           (_cached_decode, 256, 200)],
+                         ids=["mha_attention", "cached_decode_attention"])
+def test_wrapper_kernel_matches_oracle_and_refuses_untileable(make, good, bad):
+    call = make(good)
+    assert_allclose(np.asarray(call(interpret=True)),
+                    np.asarray(call(use_pallas=False)), atol=2e-5, rtol=2e-5)
+    with pytest.raises(ValueError, match="use_pallas=False"):
+        make(bad)(interpret=True)
+    make(bad)(use_pallas=False)          # the oracle takes any length
 
 
 # --------------------------------------------------------------------------- #

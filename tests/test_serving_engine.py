@@ -1,6 +1,9 @@
 """PreemptiveServingEngine behaviour: the paper's scheduler as a serving
 feature — HP deadline guarantees, LP preemption, and the beyond-paper
 resume mode (KV cache survives preemption)."""
+import importlib.util
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -166,3 +169,66 @@ def test_submit_batch_admits_lp_burst(setup):
     assert all(len(r.tokens_out) == 3 for r in lps)
     assert m.lp_requests_total == 4 and m.lp_allocated == 4
     assert m.lp_completed == 4 and m.hp_completed == 1
+
+
+# --------------------------------------------------------------------------- #
+# chip_smoke.py: its phases at smoke width on the CPU; its device check       #
+# --------------------------------------------------------------------------- #
+
+ROOT = Path(__file__).resolve().parents[1]
+
+
+@pytest.fixture(scope="module")
+def chip_smoke():
+    spec = importlib.util.spec_from_file_location("chip_smoke",
+                                                  ROOT / "chip_smoke.py")
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def test_chip_smoke_phases_at_smoke_width(chip_smoke, setup):
+    """The script's serve and correctness phases, unchanged, on the smoke
+    config: every request terminal, cached logits match the forward pass."""
+    cfg, params, _ = setup
+    cost, net = chip_smoke.cost_phase(cfg, reps=1)
+    s = chip_smoke.serve_phase(cfg, params, cost, net, n_requests=12)
+    assert s["hp_total"] == 8 and s["lp_total"] == 4
+    assert 0 < s["hp_done"] <= 8 and 0 < s["lp_done"] <= 4
+    c = chip_smoke.correctness_phase(cfg, params)
+    assert c["positions"] == chip_smoke.N_DECODE_CHECK + 1
+    assert c["rel_err"] < 1e-4              # float32 matmuls on the CPU
+    assert c["token_agree"] == c["positions"]
+
+
+def test_chip_smoke_refuses_a_host_without_tpu(chip_smoke, monkeypatch,
+                                               capsys):
+    monkeypatch.setattr("sys.argv", ["chip_smoke.py"])
+    assert chip_smoke.main() != 0
+    out = capsys.readouterr().out
+    assert "platform=cpu" in out and '"ok"' not in out
+
+
+@pytest.fixture
+def restore_cache_dir():
+    before = jax.config.jax_compilation_cache_dir
+    yield
+    jax.config.update("jax_compilation_cache_dir", before)
+
+
+@pytest.mark.parametrize("from_env", [False, True], ids=["fixed", "env"])
+def test_compile_cache_dir(from_env, tmp_path, monkeypatch,
+                           restore_cache_dir):
+    """$JAX_COMPILATION_CACHE_DIR when set, else the fixed in-checkout path
+    that git ignores; never a per-process name."""
+    from repro.launch import compile_cache
+    if from_env:
+        want = tmp_path / "jax-cache"
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(want))
+    else:
+        want = ROOT / ".jax_cache"
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        assert ".jax_cache/" in (ROOT / ".gitignore").read_text().split()
+    assert compile_cache.enable_compile_cache() == want
+    assert compile_cache.enable_compile_cache() == want
+    assert jax.config.jax_compilation_cache_dir == str(want)
