@@ -95,6 +95,24 @@ def main() -> None:
     if lat:
         print(f"  HP latency: mean {1e3*sum(lat)/len(lat):.1f}ms "
               f"max {1e3*max(lat):.1f}ms (deadline {1e3*hp_deadline:.1f}ms)")
+    print("  per request: virtual latency | wall clock: engine queue "
+          "(submitted to started), time to first token, end to end "
+          "(submitted to finished)")
+    for r in sorted(eng.done, key=lambda r: r.rid):
+        t = r.timing
+        virt = r.completed_at - r.arrival if r.state == "done" else None
+        print(f"    req {r.rid:3d} {r.priority.name:4s} {r.state:6s} "
+              f"{_ms(virt)} | {_ms(_since(t.submitted, t.started))} "
+              f"{_ms(_since(t.submitted, t.first_token))} "
+              f"{_ms(_since(t.submitted, t.finished))}")
+
+
+def _since(a, b):
+    return None if a is None or b is None else b - a
+
+
+def _ms(seconds) -> str:
+    return "       -" if seconds is None else f"{1e3 * seconds:6.1f}ms"
 
 
 if __name__ == "__main__":

@@ -140,28 +140,29 @@ def layer_apply(
     self_cache = cache.get("self") if cache else None
     h = rmsnorm(params["norm1"], x, cfg.norm_eps)
 
-    if ld.mixer == "attn":
-        out, new_self = attention.attn_apply(
-            params["mixer"], h, cfg, positions=ctx.positions, causal=ctx.causal,
-            window=ctx.window, cache=self_cache, chunk=cfg.attn_chunk,
-            inner_unroll=ctx.inner_unroll)
-        out = attention.attn_out_project(params["mixer"], out)
-    elif ld.mixer == "mla":
-        out, new_self = mla.mla_apply(
-            params["mixer"], h, cfg, positions=ctx.positions,
-            window=ctx.window, cache=self_cache)
-    elif ld.mixer == "mamba":
-        out, new_self = mamba.mamba_apply(params["mixer"], h, cfg,
-                                          cache=self_cache,
-                                          unroll=ctx.inner_unroll)
-    elif ld.mixer == "mlstm":
-        out, new_self = xlstm.mlstm_apply(params["mixer"], h, cfg,
-                                          cache=self_cache)
-    elif ld.mixer == "slstm":
-        out, new_self = xlstm.slstm_apply(params["mixer"], h, cfg,
-                                          cache=self_cache)
-    else:
-        raise ValueError(ld.mixer)
+    with jax.named_scope(ld.mixer):     # names it in the device trace
+        if ld.mixer == "attn":
+            out, new_self = attention.attn_apply(
+                params["mixer"], h, cfg, positions=ctx.positions,
+                causal=ctx.causal, window=ctx.window, cache=self_cache,
+                chunk=cfg.attn_chunk, inner_unroll=ctx.inner_unroll)
+            out = attention.attn_out_project(params["mixer"], out)
+        elif ld.mixer == "mla":
+            out, new_self = mla.mla_apply(
+                params["mixer"], h, cfg, positions=ctx.positions,
+                window=ctx.window, cache=self_cache)
+        elif ld.mixer == "mamba":
+            out, new_self = mamba.mamba_apply(params["mixer"], h, cfg,
+                                              cache=self_cache,
+                                              unroll=ctx.inner_unroll)
+        elif ld.mixer == "mlstm":
+            out, new_self = xlstm.mlstm_apply(params["mixer"], h, cfg,
+                                              cache=self_cache)
+        elif ld.mixer == "slstm":
+            out, new_self = xlstm.slstm_apply(params["mixer"], h, cfg,
+                                              cache=self_cache)
+        else:
+            raise ValueError(ld.mixer)
     x = x + out
 
     if ld.cross_attn:
@@ -177,12 +178,13 @@ def layer_apply(
 
     if ld.ffn != "none":
         h2 = rmsnorm(params["norm2"], x, cfg.norm_eps)
-        if ld.ffn == "dense":
-            x = x + ffn.ffn_apply(params["ffn"], h2)
-        else:
-            y, aux = ffn.moe_apply(params["ffn"], h2, cfg,
-                                   group_size=ctx.moe_group_size)
-            x = x + y
+        with jax.named_scope("ffn" if ld.ffn == "dense" else "moe"):
+            if ld.ffn == "dense":
+                y = ffn.ffn_apply(params["ffn"], h2)
+            else:
+                y, aux = ffn.moe_apply(params["ffn"], h2, cfg,
+                                       group_size=ctx.moe_group_size)
+        x = x + y
 
     new_cache: Optional[dict] = None
     if cache is not None:

@@ -101,6 +101,7 @@ def project_modality(params: Params, emb: jax.Array) -> jax.Array:
     return jnp.einsum("bsd,de->bse", h, params["proj_mid"])
 
 
+@jax.named_scope("lm_head")
 def lm_logits(params: Params, cfg: ModelConfig, x: jax.Array) -> jax.Array:
     if cfg.tie_embeddings:
         return jnp.einsum("btd,vd->btv", x, params["embed"])

@@ -29,6 +29,8 @@ reserved slots to pin real compute to and are rejected with a clear error.
 from __future__ import annotations
 
 import itertools
+import time
+from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Any, Optional
 
@@ -85,6 +87,36 @@ def engine_network_config(cost: CostModel, lp_tokens: int,
     )
 
 
+@dataclass
+class RequestTiming:
+    """Wall-clock lifecycle of one request, in ``time.perf_counter()``
+    seconds (only differences mean anything), beside the virtual-time
+    ``arrival`` / ``completed_at`` of its ``ServeRequest``.  Stamps stay
+    None until the event happens; the sums cover every start of the
+    request, including starts whose tokens ``lose_work`` threw away."""
+
+    submitted: Optional[float] = None    # submit() / submit_batch()
+    started: Optional[float] = None      # first entry to its compute
+    first_token: Optional[float] = None  # first token a host int
+    finished: Optional[float] = None     # engine marked it done
+    admit_s: float = 0.0                 # inside "serve.admit"
+    decode_s: float = 0.0                # inside "serve.decode"
+    decode_steps: int = 0                # serve_step calls
+
+
+@contextmanager
+def _span(name: str, attr: str, *timings: RequestTiming):
+    """A profiler annotation ``name`` around the block (on the device
+    trace's clock when a trace is on); its wall seconds are added to
+    ``attr`` of ``timings``, split evenly, so span and field agree."""
+    with jax.profiler.TraceAnnotation(name):
+        t = time.perf_counter()
+        yield
+        share = (time.perf_counter() - t) / len(timings)
+    for tm in timings:
+        setattr(tm, attr, getattr(tm, attr) + share)
+
+
 @dataclass(eq=False)                      # identity equality: the prompt is
 class ServeRequest:                       # a jax array (dataclass __eq__
                                           # would compare it elementwise)
@@ -104,6 +136,7 @@ class ServeRequest:                       # a jax array (dataclass __eq__
     completed_at: float = -1.0
     n_preemptions: int = 0
     task: Optional[Task] = None
+    timing: RequestTiming = field(default_factory=RequestTiming)
 
 
 class _ServingClient(DispatchClient):
@@ -217,6 +250,7 @@ class PreemptiveServingEngine:
             max_new_tokens=req.max_new_tokens, task_type=req.task_type,
             spec=self.net.spec)
         req.arrival = self.q.now
+        req.timing.submitted = time.perf_counter()
         self.q.push(self.q.now, lambda: self._admit(req))
 
     def submit_batch(self, reqs: list[ServeRequest]) -> None:
@@ -238,6 +272,7 @@ class PreemptiveServingEngine:
                     max_new_tokens=r.max_new_tokens, task_type=r.task_type,
                     spec=self.net.spec)
                 r.arrival = self.q.now
+                r.timing.submitted = time.perf_counter()
             self.q.push(self.q.now, lambda: self._admit_lp_batch(lp))
 
     def _make_lp(self, req: ServeRequest, now: float) -> LowPriorityRequest:
@@ -255,22 +290,25 @@ class PreemptiveServingEngine:
         return lp
 
     def _admit_lp_batch(self, reqs: list[ServeRequest]) -> None:
-        now = self.q.now
-        lps = [self._make_lp(req, now) for req in reqs]
-        self.dispatcher.submit_lp_batch(lps)
+        with _span("serve.admit", "admit_s", *(r.timing for r in reqs)):
+            now = self.q.now
+            lps = [self._make_lp(req, now) for req in reqs]
+            self.dispatcher.submit_lp_batch(lps)
 
     def _admit(self, req: ServeRequest) -> None:
-        now = self.q.now
-        if req.priority == Priority.HIGH:
-            task = Task(priority=req.priority, source_device=req.home_slice,
-                        deadline=req.deadline, frame_id=req.rid,
-                        task_type=req.task_type)
-            req.task = task
-            self._by_task[task] = req
-            self.metrics.hp_generated += 1
-            self.dispatcher.submit_hp(task)
-        else:
-            self.dispatcher.submit_lp(self._make_lp(req, now))
+        with _span("serve.admit", "admit_s", req.timing):
+            now = self.q.now
+            if req.priority == Priority.HIGH:
+                task = Task(priority=req.priority,
+                            source_device=req.home_slice,
+                            deadline=req.deadline, frame_id=req.rid,
+                            task_type=req.task_type)
+                req.task = task
+                self._by_task[task] = req
+                self.metrics.hp_generated += 1
+                self.dispatcher.submit_hp(task)
+            else:
+                self.dispatcher.submit_lp(self._make_lp(req, now))
 
     # ------------------------------------------------------------------ #
     # Execution (real compute at virtual-time slot boundaries)            #
@@ -279,32 +317,38 @@ class PreemptiveServingEngine:
         """The reserved slot began: run the request's actual jax compute."""
         req = self._by_task[task]
         req.state = "running"
-        if req.priority == Priority.HIGH:
-            nxt, _ = self._prefill(self.params, {"tokens": req.prompt})
-            req.tokens_out = [int(nxt[0])]
+        tm = req.timing
+        if tm.started is None:
+            tm.started = time.perf_counter()
+        if not self.lose_work and req.rid in self._decode_state:
+            caches, last, pos = self._decode_state[req.rid]   # LP resumes
         else:
-            # run prefill now (or resume), decode tokens as the slot elapses
-            if req.rid in self._decode_state and not self.lose_work:
-                caches, last, pos = self._decode_state[req.rid]
-            else:
-                req.tokens_out = []
+            with jax.profiler.TraceAnnotation("serve.prefill"):
                 nxt, caches = self._prefill(self.params,
                                             {"tokens": req.prompt})
-                last = nxt[:, None]
-                pos = req.prompt.shape[1]
-                req.tokens_out.append(int(nxt[0]))
-            remaining = req.max_new_tokens - len(req.tokens_out)
+                req.tokens_out = [int(nxt[0])]
+                if tm.first_token is None:
+                    tm.first_token = time.perf_counter()
+            if req.priority == Priority.HIGH:
+                return
+            last = nxt[:, None]
+            pos = req.prompt.shape[1]
+        # LP: decode the rest of its tokens as the slot elapses
+        remaining = req.max_new_tokens - len(req.tokens_out)
+        with _span("serve.decode", "decode_s", tm):
             for _ in range(remaining):
                 last, caches = self._serve(self.params, caches, last,
                                            jnp.asarray(pos, jnp.int32))
                 req.tokens_out.append(int(last[0, 0]))
                 pos += 1
-            self._decode_state[req.rid] = (caches, last, pos)
+        tm.decode_steps += remaining
+        self._decode_state[req.rid] = (caches, last, pos)
 
     def _finish_request(self, task: Task) -> None:
         req = self._by_task[task]
         req.state = "done"
         req.completed_at = self.q.now
+        req.timing.finished = time.perf_counter()
         self._decode_state.pop(req.rid, None)
         self.done.append(req)
 

@@ -1,7 +1,9 @@
 """PreemptiveServingEngine behaviour: the paper's scheduler as a serving
 feature — HP deadline guarantees, LP preemption, and the beyond-paper
 resume mode (KV cache survives preemption)."""
+import glob
 import importlib.util
+import re
 from pathlib import Path
 
 import jax
@@ -17,6 +19,7 @@ from repro.serving.engine import (
     ServeRequest,
     engine_network_config,
 )
+from repro.training.steps import make_prefill_step, make_serve_step
 
 
 @pytest.fixture(scope="module")
@@ -169,6 +172,124 @@ def test_submit_batch_admits_lp_burst(setup):
     assert all(len(r.tokens_out) == 3 for r in lps)
     assert m.lp_requests_total == 4 and m.lp_allocated == 4
     assert m.lp_completed == 4 and m.hp_completed == 1
+
+
+# --------------------------------------------------------------------------- #
+# Wall-clock request timing, engine spans and model-layer names               #
+# --------------------------------------------------------------------------- #
+
+LP_TOKENS = 4
+
+
+def _lp(cfg, seed, home=0, deadline=120.0):
+    return ServeRequest(prompt=_prompt(cfg, seed), max_new_tokens=LP_TOKENS,
+                        priority=Priority.LOW, deadline=deadline,
+                        home_slice=home)
+
+
+def _hp(cfg, net, seed=1, at=0.0, home=0):
+    return ServeRequest(prompt=_prompt(cfg, seed), max_new_tokens=1,
+                        priority=Priority.HIGH,
+                        deadline=at + net.t_hp * 2 + 0.2, home_slice=home)
+
+
+def _serve_scenario(setup, scenario):
+    """Run one scenario to its end; returns (engine, HP requests, LP
+    requests, LP starts counted at the dispatcher's ``on_start``)."""
+    cfg, params, cost = setup
+    eng, net = _engine(cfg, params, cost, lp_tokens=LP_TOKENS)
+    starts = []
+    inner = eng.dispatcher.client.on_start
+
+    def counted(task):
+        if eng._by_task[task].priority == Priority.LOW:
+            starts.append(task)
+        inner(task)
+
+    eng.dispatcher.client.on_start = counted
+    if scenario == "preempting":          # test_hp_preempts_saturating_lp
+        lps = [_lp(cfg, i + 2) for i in range(4)]
+        for lp in lps:
+            eng.submit(lp)
+        hps = [_hp(cfg, net, at=0.01)]
+        eng.q.push(0.01, lambda r=hps[0]: eng.submit(r))
+    elif scenario == "batch":
+        lps = [_lp(cfg, i + 20, home=i % 2, deadline=300.0) for i in range(4)]
+        hps = [_hp(cfg, net, seed=30)]
+        eng.submit_batch(lps + hps)
+    else:                                 # two HP and one LP, apart
+        lps = [_lp(cfg, 8, home=1)]
+        hps = [_hp(cfg, net, seed=s, home=s % 2) for s in (1, 2)]
+        for r in hps + lps:
+            eng.submit(r)
+    eng.run()
+    return eng, hps, lps, starts
+
+
+@pytest.mark.parametrize("scenario", ["apart", "preempting", "batch"])
+def test_finished_hp_timing_is_ordered(setup, scenario):
+    _, hps, _, _ = _serve_scenario(setup, scenario)
+    for r in hps:
+        assert r.state == "done"
+        t = r.timing
+        assert t.submitted <= t.started <= t.first_token <= t.finished
+        assert t.admit_s > 0
+
+
+@pytest.mark.parametrize("scenario", ["apart", "preempting", "batch"])
+def test_every_request_is_admitted_in_a_timed_span(setup, scenario):
+    """Burst members of ``submit_batch`` each get a share of the burst's
+    one admission span."""
+    _, hps, lps, _ = _serve_scenario(setup, scenario)
+    assert all(r.timing.admit_s > 0 for r in hps + lps)
+
+
+@pytest.mark.parametrize("scenario", ["apart", "batch"])
+def test_unpreempted_lp_decodes_all_but_its_prefill_token(setup, scenario):
+    _, _, lps, starts = _serve_scenario(setup, scenario)
+    assert len(starts) == len(lps)
+    for r in lps:
+        assert r.n_preemptions == 0 and r.state == "done"
+        assert r.timing.decode_steps == r.max_new_tokens - 1
+        assert r.timing.decode_s > 0
+        assert r.timing.started <= r.timing.first_token <= r.timing.finished
+
+
+def test_decode_steps_count_every_start_of_a_preempted_lp(setup):
+    """Under lose_work a victim restarts from its prompt: each start
+    decodes max_new_tokens - 1 tokens, thrown away or not."""
+    eng, hps, lps, starts = _serve_scenario(setup, "preempting")
+    assert eng.lose_work and eng.metrics.preemptions >= 1
+    assert any(starts.count(t) > 1 for t in starts)   # a victim restarted
+    assert sum(r.timing.decode_steps for r in lps) == \
+        (LP_TOKENS - 1) * len(starts)
+
+
+def test_engine_spans_land_in_the_profiler_trace(setup, tmp_path):
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        _serve_scenario(setup, "apart")
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    names = [ev.name for pl in jax.profiler.ProfileData.from_file(path).planes
+             for ln in pl.lines for ev in ln.events]
+    assert {n: names.count(n) for n in
+            ("serve.admit", "serve.prefill", "serve.decode")} == \
+        {"serve.admit": 3, "serve.prefill": 3, "serve.decode": 1}
+
+
+def test_serve_step_names_its_model_layers(setup):
+    """attention, FFN and LM head carry a ``jax.named_scope`` into the
+    lowered program's locations (and so the device trace's op metadata)."""
+    cfg, params, _ = setup
+    nxt, caches = jax.jit(make_prefill_step(cfg, 32))(
+        params, {"tokens": _prompt(cfg)})
+    text = jax.jit(make_serve_step(cfg)).lower(
+        params, caches, nxt[:, None], jnp.asarray(8, jnp.int32)
+    ).as_text(debug_info=True)
+    for scope in ("attn", "ffn", "lm_head"):
+        assert re.search(rf'loc\("([^"]*/)?{scope}/', text), scope
 
 
 # --------------------------------------------------------------------------- #
