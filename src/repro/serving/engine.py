@@ -102,6 +102,7 @@ class RequestTiming:
     admit_s: float = 0.0                 # inside "serve.admit"
     decode_s: float = 0.0                # inside "serve.decode"
     decode_steps: int = 0                # serve_step calls
+    decode_syncs: int = 0                # host waits in "serve.decode"
 
 
 @contextmanager
@@ -333,14 +334,21 @@ class PreemptiveServingEngine:
                 return
             last = nxt[:, None]
             pos = req.prompt.shape[1]
-        # LP: decode the rest of its tokens as the slot elapses
+        # LP: decode the rest of its tokens as the slot elapses.  No host
+        # read inside the loop, so step k+1 is queued while step k runs; one
+        # fetch after it reads every token of this start.
         remaining = req.max_new_tokens - len(req.tokens_out)
         with _span("serve.decode", "decode_s", tm):
+            steps = []
             for _ in range(remaining):
                 last, caches = self._serve(self.params, caches, last,
                                            jnp.asarray(pos, jnp.int32))
-                req.tokens_out.append(int(last[0, 0]))
+                steps.append(last)
                 pos += 1
+            if steps:
+                req.tokens_out.extend(
+                    int(t[0, 0]) for t in jax.device_get(steps))
+                tm.decode_syncs += 1
         tm.decode_steps += remaining
         self._decode_state[req.rid] = (caches, last, pos)
 

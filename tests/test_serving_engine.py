@@ -193,11 +193,12 @@ def _hp(cfg, net, seed=1, at=0.0, home=0):
                         deadline=at + net.t_hp * 2 + 0.2, home_slice=home)
 
 
-def _serve_scenario(setup, scenario):
+def _serve_scenario(setup, scenario, lose_work=True):
     """Run one scenario to its end; returns (engine, HP requests, LP
     requests, LP starts counted at the dispatcher's ``on_start``)."""
     cfg, params, cost = setup
-    eng, net = _engine(cfg, params, cost, lp_tokens=LP_TOKENS)
+    eng, net = _engine(cfg, params, cost, lp_tokens=LP_TOKENS,
+                       lose_work=lose_work)
     starts = []
     inner = eng.dispatcher.client.on_start
 
@@ -251,6 +252,7 @@ def test_unpreempted_lp_decodes_all_but_its_prefill_token(setup, scenario):
     for r in lps:
         assert r.n_preemptions == 0 and r.state == "done"
         assert r.timing.decode_steps == r.max_new_tokens - 1
+        assert r.timing.decode_syncs == 1
         assert r.timing.decode_s > 0
         assert r.timing.started <= r.timing.first_token <= r.timing.finished
 
@@ -263,6 +265,61 @@ def test_decode_steps_count_every_start_of_a_preempted_lp(setup):
     assert any(starts.count(t) > 1 for t in starts)   # a victim restarted
     assert sum(r.timing.decode_steps for r in lps) == \
         (LP_TOKENS - 1) * len(starts)
+
+
+@pytest.fixture(scope="module")
+def reference_tokens(setup):
+    """A request's tokens from a loop that reads each one to the host as
+    it is made (the engine's prefill and serve programs, built anew)."""
+    cfg, params, _ = setup
+    prefill = jax.jit(make_prefill_step(cfg, 256))   # the engine's cache_len
+    serve = jax.jit(make_serve_step(cfg))
+
+    def tokens(req):
+        nxt, caches = prefill(params, {"tokens": req.prompt})
+        out, last, pos = [int(nxt[0])], nxt[:, None], req.prompt.shape[1]
+        for _ in range(req.max_new_tokens - 1):
+            last, caches = serve(params, caches, last,
+                                 jnp.asarray(pos, jnp.int32))
+            out.append(int(last[0, 0]))
+            pos += 1
+        return out
+    return tokens
+
+
+@pytest.mark.parametrize("scenario,lose_work", [
+    ("apart", True), ("batch", True), ("preempting", True),
+    ("preempting", False)],
+    ids=["apart", "batch", "preempting-lose", "preempting-resume"])
+def test_lp_tokens_match_a_per_token_reference(setup, reference_tokens,
+                                               scenario, lose_work):
+    """Reading an LP start's tokens in one fetch after its decode loop
+    gives the tokens, as Python ints, that a read per token gives; a
+    preempted LP, restarted or resumed, ends with them too."""
+    eng, _, lps, _ = _serve_scenario(setup, scenario, lose_work)
+    assert eng.cache_len == 256
+    done = [r for r in lps if r.state == "done"]
+    if scenario == "preempting":
+        assert any(r.n_preemptions for r in done)
+    else:
+        assert done == lps
+    for r in done:
+        assert all(type(t) is int for t in r.tokens_out)
+        assert r.tokens_out == reference_tokens(r)
+
+
+@pytest.mark.parametrize("lose_work", [True, False], ids=["lose", "resume"])
+def test_decode_syncs_once_per_start_that_decodes(setup, lose_work):
+    """The host waits on the device once per LP start, while
+    ``decode_steps`` counts every token.  Under lose_work each restart
+    decodes again; a resumed LP has no token left to decode."""
+    _, _, lps, starts = _serve_scenario(setup, "preempting", lose_work)
+    assert any(starts.count(r.task) > 1 for r in lps)
+    for r in lps:
+        n = starts.count(r.task)
+        decoding = n if lose_work else min(n, 1)
+        assert r.timing.decode_syncs == decoding
+        assert r.timing.decode_steps == (LP_TOKENS - 1) * decoding
 
 
 def test_engine_spans_land_in_the_profiler_trace(setup, tmp_path):
