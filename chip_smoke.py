@@ -38,8 +38,8 @@ from repro.serving.engine import (  # noqa: E402
     PreemptiveServingEngine,
     ServeRequest,
     engine_network_config,
+    jit_serving_steps,
 )
-from repro.training.steps import make_prefill_step, make_serve_step  # noqa: E402
 
 ARCH = "qwen2-0.5b"
 PROMPT_LEN = 16           # every prompt, so the prefill step compiles once
@@ -135,8 +135,7 @@ def correctness_phase(cfg: ModelConfig, params, *,
     v = cfg.vocab_size
     prompt = jax.random.randint(jax.random.PRNGKey(seed + 2),
                                 (1, PROMPT_LEN), 0, v)
-    prefill_step = jax.jit(make_prefill_step(cfg, CACHE_LEN))
-    serve_step = jax.jit(make_serve_step(cfg))
+    prefill_step, serve_step = jit_serving_steps(cfg, CACHE_LEN)
     prefill = jax.jit(lambda p, b: M.prefill(p, cfg, b, CACHE_LEN))
     decode = jax.jit(lambda p, c, t, pos: M.decode_step(p, cfg, c, t, pos))
     forward = jax.jit(lambda p, b: M.forward(p, cfg, b)[0])

@@ -261,26 +261,35 @@ def stage_apply(
 ) -> tuple[jax.Array, Optional[dict], jax.Array]:
     """Scan over stage.repeats; inside, unroll the (short) pattern.
 
+    The caches ride in the scan's carry and each layer's is written back at
+    its own index, so a step that donates them updates them in place.
+    Scanned in and out as xs/ys they would need a second buffer the size of
+    every cache, and a copy of it into the donated one on each step.
+
     ``unroll=True`` fully unrolls the repeat loop — used by the roofline
     analysis so cost_analysis counts every layer (XLA cost analysis counts a
     while-loop body once; see launch/hlo_analysis.py)."""
 
-    def body(carry, xs):
-        x, aux = carry
-        p, cache = xs
-        new_caches = {}
-        for i, ld in enumerate(stage.pattern):
-            ci = cache[f"p{i}"] if cache is not None else None
-            x, nc, a = layer_apply(p[f"p{i}"], ld, x, ctx, ci)
+    def body(carry, p):
+        x, aux, i, caches = carry
+        for j, ld in enumerate(stage.pattern):
+            key = f"p{j}"
+            ci = None
+            if caches is not None:
+                ci = jax.tree.map(
+                    lambda c: jax.lax.dynamic_index_in_dim(c, i, 0, False),
+                    caches[key])
+            x, nc, a = layer_apply(p[key], ld, x, ctx, ci)
             aux = aux + a
-            if nc is not None:
-                new_caches[f"p{i}"] = nc
-        return (x, aux), (new_caches if new_caches else None)
+            if caches is not None:
+                caches = {**caches, key: jax.tree.map(
+                    lambda c, n: jax.lax.dynamic_update_index_in_dim(c, n, i, 0),
+                    caches[key], nc)}
+        return (x, aux, i + 1, caches), None
 
     if remat:
         body = jax.checkpoint(body)
 
-    aux0 = jnp.zeros((), jnp.float32)
-    (x, aux), new_caches = jax.lax.scan(body, (x, aux0), (params, caches),
-                                        unroll=unroll)
-    return x, new_caches, aux
+    carry = (x, jnp.zeros((), jnp.float32), jnp.zeros((), jnp.int32), caches)
+    (x, aux, _, caches), _ = jax.lax.scan(body, carry, params, unroll=unroll)
+    return x, caches, aux

@@ -25,7 +25,6 @@ import numpy as np
 
 from ..models import model as M
 from ..models.config import ModelConfig
-from ..training.steps import make_prefill_step, make_serve_step
 
 
 @dataclass
@@ -113,25 +112,33 @@ def measure_cost_model(
         batch_d["modality_emb"] = jax.random.normal(
             key, (batch, n_mod, cfg.modality_embed_dim))
 
-    pre = jax.jit(make_prefill_step(cfg, cache_len))
-    srv = jax.jit(make_serve_step(cfg))
+    # The engine's own steps (imported here: the engine imports this module).
+    from .engine import jit_serving_steps
+    pre, srv = jit_serving_steps(cfg, cache_len)
     # One untimed call of each step, so no compile lands in the timed reps.
-    nxt, caches = jax.tree.map(jnp.asarray, pre(params, batch_d))
+    nxt, caches = pre(params, batch_d)
+    token = nxt[:, None]
     pos = jnp.asarray(prompt_len, jnp.int32)
-    jax.block_until_ready(srv(params, caches, nxt[:, None], pos))
+    caches = jax.block_until_ready(srv(params, caches, token, pos))[1]
 
-    def timeit(fn, *a):
+    def timeit(fn):
         ts = []
-        out = None
         for _ in range(reps):
             t0 = time.perf_counter()
-            out = fn(*a)
-            jax.block_until_ready(out)
+            jax.block_until_ready(fn())
             ts.append(time.perf_counter() - t0)
-        return float(np.mean(ts)), float(np.std(ts)), out
+        return float(np.mean(ts)), float(np.std(ts))
 
-    p_mean, p_std, _ = timeit(pre, params, batch_d)
-    d_mean, d_std, _ = timeit(srv, params, caches, nxt[:, None], pos)
+    def decode():
+        # the serve step donates its caches: each rep steps the cache the
+        # previous one returned, as the engine's decode loop does
+        nonlocal caches
+        out = srv(params, caches, token, pos)
+        caches = out[1]
+        return out
+
+    p_mean, p_std = timeit(lambda: pre(params, batch_d))
+    d_mean, d_std = timeit(decode)
 
     # paper-calibrated parallel efficiency: every doubling of the degree
     # multiplies the step time by t(4) / t(2) = 11.611 / 16.862; the

@@ -87,6 +87,15 @@ def engine_network_config(cost: CostModel, lp_tokens: int,
     )
 
 
+def jit_serving_steps(cfg: ModelConfig, cache_len: int):
+    """The jitted ``(prefill_step, serve_step)`` of the served path.  The
+    serve step donates its caches, so each step writes its K/V into the
+    cache it was given: a request holds one cache however many steps are
+    queued ahead of the device.  Never pass a cache to it twice."""
+    return (jax.jit(make_prefill_step(cfg, cache_len)),
+            jax.jit(make_serve_step(cfg), donate_argnums=1))
+
+
 @dataclass
 class RequestTiming:
     """Wall-clock lifecycle of one request, in ``time.perf_counter()``
@@ -100,6 +109,8 @@ class RequestTiming:
     first_token: Optional[float] = None  # first token a host int
     finished: Optional[float] = None     # engine marked it done
     admit_s: float = 0.0                 # inside "serve.admit"
+    prefill_s: float = 0.0               # inside "serve.prefill"
+    prefills: int = 0                    # prefill_step calls
     decode_s: float = 0.0                # inside "serve.decode"
     decode_steps: int = 0                # serve_step calls
     decode_syncs: int = 0                # host waits in "serve.decode"
@@ -164,7 +175,6 @@ class _ServingClient(DispatchClient):
         req.n_preemptions += 1
         req.state = "preempted"
         if eng.lose_work:
-            eng._decode_state.pop(req.rid, None)
             req.tokens_out = []
 
     def on_admit_fail(self, task: Task) -> None:
@@ -236,10 +246,11 @@ class PreemptiveServingEngine:
             self.policy, self.q, self.net, self.metrics,
             client=_ServingClient(self), exact_slots=True,
         )
-        self._prefill = jax.jit(make_prefill_step(cfg, cache_len))
-        self._serve = jax.jit(make_serve_step(cfg))
+        self._prefill, self._serve = jit_serving_steps(cfg, cache_len)
         self._by_task: dict[Task, ServeRequest] = {}
-        self._decode_state: dict[int, tuple] = {}   # rid -> (caches, last, pos)
+        # rid -> (caches, last, pos), kept only without lose_work: the one
+        # case where a later start of the request reads it back
+        self._decode_state: dict[int, tuple] = {}
         self.done: list[ServeRequest] = []
 
     # ------------------------------------------------------------------ #
@@ -321,15 +332,17 @@ class PreemptiveServingEngine:
         tm = req.timing
         if tm.started is None:
             tm.started = time.perf_counter()
-        if not self.lose_work and req.rid in self._decode_state:
-            caches, last, pos = self._decode_state[req.rid]   # LP resumes
+        if req.rid in self._decode_state:
+            # LP resumes; the state leaves the table, as the step donates it
+            caches, last, pos = self._decode_state.pop(req.rid)
         else:
-            with jax.profiler.TraceAnnotation("serve.prefill"):
+            with _span("serve.prefill", "prefill_s", tm):
                 nxt, caches = self._prefill(self.params,
                                             {"tokens": req.prompt})
                 req.tokens_out = [int(nxt[0])]
                 if tm.first_token is None:
                     tm.first_token = time.perf_counter()
+            tm.prefills += 1
             if req.priority == Priority.HIGH:
                 return
             last = nxt[:, None]
@@ -350,7 +363,8 @@ class PreemptiveServingEngine:
                     int(t[0, 0]) for t in jax.device_get(steps))
                 tm.decode_syncs += 1
         tm.decode_steps += remaining
-        self._decode_state[req.rid] = (caches, last, pos)
+        if not self.lose_work:
+            self._decode_state[req.rid] = (caches, last, pos)
 
     def _finish_request(self, task: Task) -> None:
         req = self._by_task[task]

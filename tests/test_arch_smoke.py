@@ -110,3 +110,21 @@ def test_param_counts_roughly_match_names():
     for arch, (target, frac) in tol.items():
         n = get_config(arch).param_count()
         assert abs(n - target) / target < frac, (arch, n, target)
+
+
+def test_phi3_mini_carries_its_published_values():
+    """Phi-3-mini-4k's config.json: MHA with 32 KV heads of 96, RoPE 1e4,
+    RMSNorm eps 1e-5, no q/k/v bias, an untied head and a 2047-token
+    sliding window; the long_500k shape still swaps in the long-context
+    window."""
+    from repro.configs.shapes import SHAPES
+    from repro.launch.build import adapt_config
+
+    cfg = get_config("phi3-mini-3.8b")
+    assert (cfg.n_kv_heads, cfg.resolved_head_dim) == (32, 96)
+    assert cfg.sliding_window == 2047
+    assert (cfg.rope_theta, cfg.norm_eps) == (1e4, 1e-5)
+    assert not cfg.qkv_bias and not cfg.tie_embeddings
+    long = adapt_config(cfg, SHAPES["long_500k"])
+    assert long.sliding_window == cfg.long_context_window
+    assert adapt_config(cfg, SHAPES["decode_32k"]).sliding_window == 2047

@@ -102,3 +102,28 @@ def test_measure_cost_model_rejects_bad_degrees(bad):
     with pytest.raises(ValueError):
         measure_cost_model(cfg, prompt_len=8, cache_len=16, reps=1,
                            degrees=bad)
+
+
+def test_cost_model_times_the_engines_own_donated_steps(monkeypatch):
+    """The cost model and the engine build their steps with one helper, so
+    the cost model times the serve step that donates its caches; its decode
+    reps thread each returned cache into the next (a donated cache handed
+    in again would raise)."""
+    import jax
+
+    from repro.models import model as M
+    from repro.serving import engine as E
+
+    made, real = [], E.jit_serving_steps
+
+    def spy(cfg, cache_len):
+        made.append(cache_len)
+        return real(cfg, cache_len)
+
+    monkeypatch.setattr(E, "jit_serving_steps", spy)
+    cfg = get_smoke_config("smollm-135m")
+    cm = measure_cost_model(cfg, prompt_len=8, cache_len=16, reps=3)
+    assert cm.decode[2].mean_s > 0.0 and cm.prefill[1].mean_s > 0.0
+    E.PreemptiveServingEngine(cfg, M.init_params(cfg, jax.random.PRNGKey(0)),
+                              cm, cache_len=16)
+    assert made == [16, 16]
