@@ -3,7 +3,7 @@ scan-over-repeats stage machinery.
 
 A stage's parameters are stacked along a leading ``layers`` axis and executed
 with ``jax.lax.scan`` so the HLO is O(1) in depth.  Caches are stacked the
-same way and threaded through the scan as per-iteration inputs/outputs.
+same way and carried through the scan (see ``stage_apply``).
 """
 from __future__ import annotations
 
@@ -133,11 +133,19 @@ def layer_apply(
     x: jax.Array,
     ctx: LayerCtx,
     cache: Optional[dict] = None,
+    layer: jax.Array | int = 0,
 ) -> tuple[jax.Array, Optional[dict], jax.Array]:
-    """Returns (x, new_cache, aux_loss)."""
+    """Returns (x, new_cache, aux_loss).
+
+    ``cache`` is the stage's stacked cache for this layer's place in the
+    pattern (leaves [repeats, ...]) and ``layer`` the repeat to use; the
+    stacked cache comes back updated (see ``stage_apply``)."""
     cfg = ctx.cfg
     aux = jnp.zeros((), jnp.float32)
-    self_cache = cache.get("self") if cache else None
+    stacked = cache.get("self") if cache else None
+    in_place = ld.mixer == "attn"
+    self_cache = (stacked if in_place or stacked is None
+                  else _at(stacked, layer))
     h = rmsnorm(params["norm1"], x, cfg.norm_eps)
 
     with jax.named_scope(ld.mixer):     # names it in the device trace
@@ -145,7 +153,8 @@ def layer_apply(
             out, new_self = attention.attn_apply(
                 params["mixer"], h, cfg, positions=ctx.positions,
                 causal=ctx.causal, window=ctx.window, cache=self_cache,
-                chunk=cfg.attn_chunk, inner_unroll=ctx.inner_unroll)
+                layer=layer, chunk=cfg.attn_chunk,
+                inner_unroll=ctx.inner_unroll)
             out = attention.attn_out_project(params["mixer"], out)
         elif ld.mixer == "mla":
             out, new_self = mla.mla_apply(
@@ -169,12 +178,10 @@ def layer_apply(
         assert ctx.enc_out is not None or (cache and "cross" in cache)
         hx = rmsnorm(params["norm_x"], x, cfg.norm_eps)
         if cache and "cross" in cache and ctx.enc_out is None:
-            ckv = cache["cross"]
+            ckv = _at(cache["cross"], layer)
         else:
             ckv = attention.cross_kv(params["cross"], ctx.enc_out)
         x = x + attention.cross_attend(params["cross"], hx, ckv, cfg)
-    else:
-        ckv = None
 
     if ld.ffn != "none":
         h2 = rmsnorm(params["norm2"], x, cfg.norm_eps)
@@ -188,14 +195,24 @@ def layer_apply(
 
     new_cache: Optional[dict] = None
     if cache is not None:
-        new_cache = {}
-        if new_self is not None:
-            new_cache["self"] = new_self
-        elif self_cache is not None:
-            new_cache["self"] = self_cache
-        if ld.cross_attn:
-            new_cache["cross"] = ckv if "cross" not in cache else cache["cross"]
+        new_cache = dict(cache)     # the cross cache is read-only
+        if stacked is not None:
+            new_cache["self"] = (new_self if in_place
+                                 else _put(stacked, new_self, layer))
     return x, new_cache, aux
+
+
+def _at(stacked: dict, i: jax.Array | int) -> dict:
+    """Layer ``i``'s slice of a stacked cache."""
+    return jax.tree.map(lambda c: jax.lax.dynamic_index_in_dim(c, i, 0, False),
+                        stacked)
+
+
+def _put(stacked: dict, new: dict, i: jax.Array | int) -> dict:
+    """``stacked`` with layer ``i``'s slice replaced by ``new``."""
+    return jax.tree.map(
+        lambda c, n: jax.lax.dynamic_update_index_in_dim(c, n, i, 0),
+        stacked, new)
 
 
 # --------------------------------------------------------------------------- #
@@ -261,10 +278,17 @@ def stage_apply(
 ) -> tuple[jax.Array, Optional[dict], jax.Array]:
     """Scan over stage.repeats; inside, unroll the (short) pattern.
 
-    The caches ride in the scan's carry and each layer's is written back at
-    its own index, so a step that donates them updates them in place.
-    Scanned in and out as xs/ys they would need a second buffer the size of
-    every cache, and a copy of it into the donated one on each step.
+    The caches ride in the scan's carry, stacked, so a step that donates
+    them updates them in place.  Scanned in and out as xs/ys they would need
+    a second buffer the size of every cache, and a copy of it into the
+    donated one on each step.  An ``attn`` layer writes its new token's K/V
+    row into the stacked cache and reads its K/V straight from it: taking
+    its slice out and writing it back would move the whole cache several
+    times a token for a one-row change.  The other mixers (``mla``,
+    ``mamba``, ``mlstm``, ``slstm``) take their layer's slice out and write
+    it back whole: the recurrent ones replace their whole state each step,
+    and an MLA cache holds one latent of a few hundred values a position,
+    a small share of an MHA cache's.  A cross-attention cache is only read.
 
     ``unroll=True`` fully unrolls the repeat loop — used by the roofline
     analysis so cost_analysis counts every layer (XLA cost analysis counts a
@@ -274,17 +298,11 @@ def stage_apply(
         x, aux, i, caches = carry
         for j, ld in enumerate(stage.pattern):
             key = f"p{j}"
-            ci = None
-            if caches is not None:
-                ci = jax.tree.map(
-                    lambda c: jax.lax.dynamic_index_in_dim(c, i, 0, False),
-                    caches[key])
-            x, nc, a = layer_apply(p[key], ld, x, ctx, ci)
+            x, nc, a = layer_apply(p[key], ld, x, ctx,
+                                   None if caches is None else caches[key], i)
             aux = aux + a
             if caches is not None:
-                caches = {**caches, key: jax.tree.map(
-                    lambda c, n: jax.lax.dynamic_update_index_in_dim(c, n, i, 0),
-                    caches[key], nc)}
+                caches = {**caches, key: nc}
         return (x, aux, i + 1, caches), None
 
     if remat:

@@ -1,10 +1,23 @@
 """GQA/MHA attention with RoPE, optional QKV bias, sliding windows and a
 position-tracked (optionally rotating) KV cache.
 
-Cache layout: k/v [B, S, KV, D] with an int32 ``positions [B, S]`` slot map
+Cache layout: k/v [B, KV, D, S] with an int32 ``positions [B, S]`` slot map
 (-1 = empty).  Full causal caches write slot ``pos``; sliding-window caches
 write slot ``pos % window`` — the same attention code handles both because
 masks are derived from the stored absolute positions, never from slot order.
+
+Decode works on the stage's stacked cache (k/v [L, B, KV, D, S],
+``positions`` [L, B, S]) and a layer index: it writes the new token's K/V
+into its slot in place and reads the layer's K/V straight out of the
+stacked buffers, so a step reads each layer's K/V once and writes one
+slot.  The other mixers take their layer's slice out and write it back
+whole (``blocks.stage_apply`` says why); for K/V that was a slice copy, a
+layout copy each way and the write-back, each over the whole layer, every
+token.  The slots are the minor axis because that is how the TPU lays out
+a cache whose head size is no multiple of 128 (so it pads none of it), and
+the slot write is held to that layout: left free, XLA copies the whole
+cache into a layout with the slot axis major before the layer scan, and
+back after it.
 """
 from __future__ import annotations
 
@@ -12,6 +25,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from ..config import ModelConfig
 from .common import apply_rope, dense_init, masked_softmax, rope_cos_sin, zeros
@@ -59,16 +73,16 @@ def attn_axes(cfg: ModelConfig) -> dict:
 
 def init_kv_cache(batch: int, length: int, n_kv: int, head_dim: int, dtype) -> dict:
     return {
-        "k": jnp.zeros((batch, length, n_kv, head_dim), dtype=dtype),
-        "v": jnp.zeros((batch, length, n_kv, head_dim), dtype=dtype),
+        "k": jnp.zeros((batch, n_kv, head_dim, length), dtype=dtype),
+        "v": jnp.zeros((batch, n_kv, head_dim, length), dtype=dtype),
         "positions": jnp.full((batch, length), -1, dtype=jnp.int32),
     }
 
 
 def kv_cache_axes() -> dict:
     return {
-        "k": ("batch", "cache", "kv_heads", "head_dim"),
-        "v": ("batch", "cache", "kv_heads", "head_dim"),
+        "k": ("batch", "kv_heads", "head_dim", "cache"),
+        "v": ("batch", "kv_heads", "head_dim", "cache"),
         "positions": ("batch", "cache"),
     }
 
@@ -82,16 +96,16 @@ def _write_slot(cache_len: int, pos: jax.Array, window: int) -> jax.Array:
 # --------------------------------------------------------------------------- #
 
 
-def _gqa_scores_to_out(q, k, v, mask) -> jax.Array:
-    """q [B,T,H,D], k/v [B,S,KV,D], mask [B|1, 1, T, S]."""
+def _gqa_scores_to_out(q, k, v, mask, kv: str = "bskd") -> jax.Array:
+    """q [B,T,H,D], k/v laid out as ``kv`` says (the sequence's [B,S,KV,D]
+    or the cache's "bkds"), mask [B|1, 1, T, S]."""
     b, t, h, d = q.shape
-    kv = k.shape[2]
-    group = h // kv
-    q = q.reshape(b, t, kv, group, d)
+    group = h // k.shape[kv.index("k")]
+    q = q.reshape(b, t, h // group, group, d)
     scale = jnp.asarray(d, jnp.float32) ** -0.5
-    scores = jnp.einsum("btkgd,bskd->bkgts", q, k) * scale
+    scores = jnp.einsum(f"btkgd,{kv}->bkgts", q, k) * scale
     w = masked_softmax(scores, mask[:, :, None])      # [B,1,1,T,S] broadcast
-    out = jnp.einsum("bkgts,bskd->btkgd", w.astype(v.dtype), v)
+    out = jnp.einsum(f"bkgts,{kv}->btkgd", w.astype(v.dtype), v)
     return out.reshape(b, t, h, d)
 
 
@@ -135,9 +149,8 @@ def attn_apply(
     positions: jax.Array,               # [T] absolute positions
     causal: bool = True,
     window: int = 0,
-    kv_x: Optional[jax.Array] = None,   # cross-attention source [B, S, d]
-    kv_positions: Optional[jax.Array] = None,
-    cache: Optional[dict] = None,       # decode: attend over cache
+    cache: Optional[dict] = None,       # decode: the stage's stacked cache
+    layer: jax.Array | int = 0,         # decode: this layer's index in it
     rope: bool = True,
     chunk: int = 0,                     # blocked attention (0 = naive)
     inner_unroll: int | bool = 1,
@@ -153,57 +166,55 @@ def attn_apply(
         cos_q, sin_q = rope_cos_sin(positions, hd, cfg.rope_theta)
         q = apply_rope(q, cos_q, sin_q)
 
+    k = jnp.einsum("bsd,dhk->bshk", x, params["wk"])
+    v = jnp.einsum("bsd,dhk->bshk", x, params["wv"])
+    if "bk" in params:
+        k = k + params["bk"]
+        v = v + params["bv"]
+    if rope:
+        cos_k, sin_k = rope_cos_sin(positions, hd, cfg.rope_theta)
+        k = apply_rope(k, cos_k, sin_k)
+
     if cache is None:
-        src = x if kv_x is None else kv_x
-        k = jnp.einsum("bsd,dhk->bshk", src, params["wk"])
-        v = jnp.einsum("bsd,dhk->bshk", src, params["wv"])
-        if "bk" in params:
-            k = k + params["bk"]
-            v = v + params["bv"]
-        k_pos = positions if kv_x is None else kv_positions
-        if rope and kv_x is None:
-            cos_k, sin_k = rope_cos_sin(k_pos, hd, cfg.rope_theta)
-            k = apply_rope(k, cos_k, sin_k)
-        if causal and kv_x is None and chunk and t > chunk:
-            return _chunked_attention(q, k, v, positions, k_pos, window,
+        if causal and chunk and t > chunk:
+            return _chunked_attention(q, k, v, positions, positions, window,
                                       chunk, inner_unroll), None
-        if causal and kv_x is None:
-            mask = causal_mask(positions, k_pos, window)[None, None]
+        if causal:
+            mask = causal_mask(positions, positions, window)[None, None]
         else:
-            mask = jnp.ones((1, 1, t, k.shape[1]), dtype=bool)
+            mask = jnp.ones((1, 1, t, t), dtype=bool)
         return _gqa_scores_to_out(q, k, v, mask), None
 
-    # ---- decode against the cache (T == 1) ------------------------------- #
+    # ---- decode (T == 1): write ``slot`` of layer ``layer``, read it ---- #
     pos = positions[-1]                               # scalar current position
-    cache_len = cache["k"].shape[1]
-    new_cache = cache
-    if kv_x is None:                                  # self-attention: write
-        k_new = jnp.einsum("btd,dhk->bthk", x, params["wk"])
-        v_new = jnp.einsum("btd,dhk->bthk", x, params["wv"])
-        if "bk" in params:
-            k_new = k_new + params["bk"]
-            v_new = v_new + params["bv"]
-        if rope:
-            cos_k, sin_k = rope_cos_sin(positions, hd, cfg.rope_theta)
-            k_new = apply_rope(k_new, cos_k, sin_k)
-        slot = _write_slot(cache_len, pos, window)
-        new_cache = {
-            "k": jax.lax.dynamic_update_slice_in_dim(cache["k"], k_new, slot, 1),
-            "v": jax.lax.dynamic_update_slice_in_dim(cache["v"], v_new, slot, 1),
-            "positions": jax.lax.dynamic_update_slice_in_dim(
-                cache["positions"],
-                jnp.broadcast_to(pos, (b, 1)).astype(jnp.int32),
-                slot,
-                1,
-            ),
-        }
-    k, v, stored = new_cache["k"], new_cache["v"], new_cache["positions"]
+    slot = _write_slot(cache["k"].shape[-1], pos, window)
+    new_cache = {
+        "k": _row_major(jax.lax.dynamic_update_slice(
+            cache["k"], _as_slot(k), (layer, 0, 0, 0, slot))),
+        "v": _row_major(jax.lax.dynamic_update_slice(
+            cache["v"], _as_slot(v), (layer, 0, 0, 0, slot))),
+        "positions": jax.lax.dynamic_update_slice(
+            cache["positions"], jnp.full((1, b, 1), pos, jnp.int32),
+            (layer, 0, slot)),
+    }
+    k, v, stored = (jax.lax.dynamic_index_in_dim(new_cache[n], layer, 0, False)
+                    for n in ("k", "v", "positions"))
     valid = (stored >= 0) & (stored <= pos)
     if window > 0:
         valid &= stored > pos - window
     mask = valid[:, None, None, :]                    # [B, 1, T=1, S]
-    out = _gqa_scores_to_out(q, k, v, mask)
+    out = _gqa_scores_to_out(q, k, v, mask, kv="bkds")
     return out, new_cache
+
+
+def _as_slot(kv: jax.Array) -> jax.Array:
+    """One position's [B, 1, KV, D] as the update of one stacked slot."""
+    return kv[None, :, 0, :, :, None]                 # [1, B, KV, D, 1]
+
+
+def _row_major(x: jax.Array) -> jax.Array:
+    """``x`` held to its row-major layout, the cache's own."""
+    return with_layout_constraint(x, Layout(tuple(range(x.ndim))))
 
 
 def attn_out_project(params: dict, attn_out: jax.Array) -> jax.Array:

@@ -312,8 +312,9 @@ def _fill_kv(p, h, cfg, ctx, cache, cache_len):
     from .layers.common import rope_cos_sin, apply_rope
     cos, sin = rope_cos_sin(ctx.positions, cfg.resolved_head_dim, cfg.rope_theta)
     k = apply_rope(k, cos, sin)
-    return _scatter_tail(cache, {"k": k, "v": v}, ctx.positions, cache_len,
-                         ctx.window)
+    return _scatter_tail(cache, {"k": jnp.moveaxis(k, 1, -1),
+                                 "v": jnp.moveaxis(v, 1, -1)},
+                         ctx.positions, cache_len, ctx.window, axis=-1)
 
 
 def _fill_mla(p, h, cfg, ctx, cache, cache_len):
@@ -324,8 +325,9 @@ def _fill_mla(p, h, cfg, ctx, cache, cache_len):
 
 
 def _scatter_tail(cache: dict, seqs: dict, positions: jax.Array,
-                  cache_len: int, window: int) -> dict:
-    """Write per-position values into the cache honouring rotation."""
+                  cache_len: int, window: int, axis: int = 1) -> dict:
+    """Write per-position values into the cache honouring rotation; the
+    positions of ``seqs`` lie along ``axis``, as the cache's slots do."""
     t = positions.shape[0]
     b = next(iter(seqs.values())).shape[0]
     new = dict(cache)
@@ -333,8 +335,9 @@ def _scatter_tail(cache: dict, seqs: dict, positions: jax.Array,
         # contiguous write at slot positions[0] (prefill starts at 0)
         n = min(t, cache_len)
         for name, val in seqs.items():
+            tail = jax.lax.slice_in_dim(val, t - n, t, axis=axis)
             new[name] = jax.lax.dynamic_update_slice_in_dim(
-                cache[name], val[:, -n:].astype(cache[name].dtype), 0, 1)
+                cache[name], tail.astype(cache[name].dtype), 0, axis)
         pos_row = jnp.full((cache_len,), -1, jnp.int32).at[:n].set(
             positions[-n:].astype(jnp.int32))
         new["positions"] = jnp.broadcast_to(pos_row, (b, cache_len))
@@ -343,8 +346,9 @@ def _scatter_tail(cache: dict, seqs: dict, positions: jax.Array,
     tail_pos = positions[-cache_len:]
     slots = tail_pos % cache_len
     for name, val in seqs.items():
-        tail = val[:, -cache_len:].astype(cache[name].dtype)
-        new[name] = cache[name].at[:, slots].set(tail)
+        tail = jax.lax.slice_in_dim(val, t - cache_len, t, axis=axis)
+        at = (slice(None),) * (axis % val.ndim) + (slots,)
+        new[name] = cache[name].at[at].set(tail.astype(cache[name].dtype))
     pos_row = jnp.zeros((cache_len,), jnp.int32).at[slots].set(
         tail_pos.astype(jnp.int32))
     new["positions"] = jnp.broadcast_to(pos_row, (b, cache_len))

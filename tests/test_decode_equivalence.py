@@ -57,10 +57,26 @@ def test_prefill_then_decode_matches_forward(arch):
     assert err_dec < TOL, f"decode mismatch {err_dec}"
 
 
-@pytest.mark.parametrize("arch", ["smollm-135m", "qwen2-0.5b"])
-def test_multi_step_decode_chain(arch):
+def _twice(cfg):
+    """The config with each stage's pattern repeated twice, so a decode
+    step scans two layers of each kind."""
+    stages = tuple(replace(st, repeats=2) for st in cfg.stages)
+    return replace(cfg, stages=stages,
+                   n_layers=sum(st.n_layers for st in stages))
+
+
+@pytest.mark.parametrize("arch,widen", [
+    ("smollm-135m", None),
+    ("qwen2-0.5b", None),
+    # attention (its K/V row written into the stacked cache) beside mamba
+    # (its state taken out and written back) in one layer scan
+    ("jamba-1.5-large-398b", _twice),
+], ids=["smollm-135m", "qwen2-0.5b", "attn-and-mamba"])
+def test_multi_step_decode_chain(arch, widen):
     """Three consecutive decode steps track the full forward."""
     cfg = _uncap(get_smoke_config(arch))
+    if widen is not None:
+        cfg = widen(cfg)
     params = M.init_params(cfg, jax.random.PRNGKey(0))
     tokens = jax.random.randint(jax.random.PRNGKey(1), (2, T + 3), 0,
                                 cfg.vocab_size)
@@ -74,7 +90,11 @@ def test_multi_step_decode_chain(arch):
         assert err < TOL, f"step {i}: {err}"
 
 
-def test_sliding_window_decode_matches_windowed_forward():
+@pytest.mark.parametrize("prompt,steps", [
+    (16, 4),     # the prompt overruns the 8-slot ring; decode writes on
+    (4, 12),     # the prompt fits; decode's own writes run past slot 7
+], ids=["prefill-wraps", "decode-wraps"])
+def test_sliding_window_decode_matches_windowed_forward(prompt, steps):
     """Rotating cache + window masks == full-seq sliding-window attention."""
     cfg = _uncap(get_smoke_config("smollm-135m"))
     cfg = replace(cfg, sliding_window=8)
@@ -83,10 +103,10 @@ def test_sliding_window_decode_matches_windowed_forward():
                                 cfg.vocab_size)
     full_logits, _ = M.forward(params, cfg, {"tokens": tokens})
     # cache_len == window -> rotating writes
-    _, caches = M.prefill(params, cfg, {"tokens": tokens[:, :16]},
+    _, caches = M.prefill(params, cfg, {"tokens": tokens[:, :prompt]},
                           cache_len=8)
-    for i in range(4):
+    for i in range(prompt, prompt + steps):
         dec_logits, caches = M.decode_step(
-            params, cfg, caches, tokens[:, 16 + i:17 + i], jnp.int32(16 + i))
-        err = float(jnp.abs(dec_logits[:, 0] - full_logits[:, 16 + i]).max())
-        assert err < TOL, f"windowed step {i}: {err}"
+            params, cfg, caches, tokens[:, i:i + 1], jnp.int32(i))
+        err = float(jnp.abs(dec_logits[:, 0] - full_logits[:, i]).max())
+        assert err < TOL, f"windowed step at {i}: {err}"
