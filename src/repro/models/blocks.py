@@ -1,62 +1,45 @@
 """Layer dispatch (mixer + FFN + optional cross-attention) and the
 scan-over-repeats stage machinery.
 
+``MIXERS`` maps a ``LayerDef.mixer`` name to its ``Mixer``: its params, its
+cache and its apply, each the layer module's own.  Each mixer writes its
+own cache, for a prompt and for a decode step alike; a layer here knows no
+mixer by name.
+
 A stage's parameters are stacked along a leading ``layers`` axis and executed
 with ``jax.lax.scan`` so the HLO is O(1) in depth.  Caches are stacked the
 same way and carried through the scan (see ``stage_apply``).
 """
 from __future__ import annotations
 
-from dataclasses import dataclass
-from typing import Any, Optional
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
 from .config import LayerDef, ModelConfig, StageDef
 from .layers import attention, ffn, mamba, mla, xlstm
-from .layers.common import rmsnorm, rmsnorm_axes, rmsnorm_init
+from .layers.common import LayerCtx, rmsnorm, rmsnorm_axes, rmsnorm_init
 
-
-@dataclass
-class LayerCtx:
-    """Everything a layer needs besides params/x/cache."""
-
-    cfg: ModelConfig
-    positions: jax.Array                  # [T] absolute positions
-    causal: bool = True
-    window: int = 0                       # sliding window (0 = full)
-    enc_out: Optional[jax.Array] = None   # encoder output for cross-attn
-    decode: bool = False
-    moe_group_size: int = 256
-    inner_unroll: int | bool = 1          # unroll inner (chunk) scans too
+MIXERS = {
+    "attn": attention.MIXER,
+    "mla": mla.MIXER,
+    "mamba": mamba.MIXER,
+    "mlstm": xlstm.MLSTM,
+    "slstm": xlstm.SLSTM,
+}
 
 
 # --------------------------------------------------------------------------- #
 # Single layer                                                                #
 # --------------------------------------------------------------------------- #
 
-_MIXER_INIT = {
-    "attn": attention.attn_init,
-    "mla": mla.mla_init,
-    "mamba": mamba.mamba_init,
-    "mlstm": xlstm.mlstm_init,
-    "slstm": xlstm.slstm_init,
-}
-_MIXER_AXES = {
-    "attn": attention.attn_axes,
-    "mla": mla.mla_axes,
-    "mamba": mamba.mamba_axes,
-    "mlstm": xlstm.mlstm_axes,
-    "slstm": xlstm.slstm_axes,
-}
-
 
 def layer_init(key, ld: LayerDef, cfg: ModelConfig, dtype) -> dict:
     keys = jax.random.split(key, 4)
     p: dict = {
         "norm1": rmsnorm_init(cfg.d_model, dtype),
-        "mixer": _MIXER_INIT[ld.mixer](keys[0], cfg, dtype),
+        "mixer": MIXERS[ld.mixer].init(keys[0], cfg, dtype),
     }
     if ld.ffn != "none":
         p["norm2"] = rmsnorm_init(cfg.d_model, dtype)
@@ -73,7 +56,7 @@ def layer_init(key, ld: LayerDef, cfg: ModelConfig, dtype) -> dict:
 def layer_axes(ld: LayerDef, cfg: ModelConfig) -> dict:
     a: dict = {
         "norm1": rmsnorm_axes(),
-        "mixer": _MIXER_AXES[ld.mixer](cfg),
+        "mixer": MIXERS[ld.mixer].axes(cfg),
     }
     if ld.ffn != "none":
         a["norm2"] = rmsnorm_axes()
@@ -86,18 +69,7 @@ def layer_axes(ld: LayerDef, cfg: ModelConfig) -> dict:
 
 def layer_cache_init(ld: LayerDef, cfg: ModelConfig, batch: int,
                      cache_len: int, dtype, enc_len: int = 0) -> dict:
-    c: dict = {}
-    if ld.mixer == "attn":
-        c["self"] = attention.init_kv_cache(
-            batch, cache_len, cfg.n_kv_heads, cfg.resolved_head_dim, dtype)
-    elif ld.mixer == "mla":
-        c["self"] = mla.init_mla_cache(batch, cache_len, cfg, dtype)
-    elif ld.mixer == "mamba":
-        c["self"] = mamba.init_mamba_cache(batch, cfg, dtype)
-    elif ld.mixer == "mlstm":
-        c["self"] = xlstm.init_mlstm_cache(batch, cfg, dtype)
-    elif ld.mixer == "slstm":
-        c["self"] = xlstm.init_slstm_cache(batch, cfg, dtype)
+    c = {"self": MIXERS[ld.mixer].cache_init(batch, cache_len, cfg, dtype)}
     if ld.cross_attn:
         hd = cfg.resolved_head_dim
         c["cross"] = {
@@ -108,17 +80,7 @@ def layer_cache_init(ld: LayerDef, cfg: ModelConfig, batch: int,
 
 
 def layer_cache_axes(ld: LayerDef) -> dict:
-    c: dict = {}
-    if ld.mixer == "attn":
-        c["self"] = attention.kv_cache_axes()
-    elif ld.mixer == "mla":
-        c["self"] = mla.mla_cache_axes()
-    elif ld.mixer == "mamba":
-        c["self"] = mamba.mamba_cache_axes()
-    elif ld.mixer == "mlstm":
-        c["self"] = xlstm.mlstm_cache_axes()
-    elif ld.mixer == "slstm":
-        c["self"] = xlstm.slstm_cache_axes()
+    c = {"self": MIXERS[ld.mixer].cache_axes()}
     if ld.cross_attn:
         c["cross"] = {
             "k": ("batch", "cache", "kv_heads", "head_dim"),
@@ -139,48 +101,33 @@ def layer_apply(
 
     ``cache`` is the stage's stacked cache for this layer's place in the
     pattern (leaves [repeats, ...]) and ``layer`` the repeat to use; the
-    stacked cache comes back updated (see ``stage_apply``)."""
+    stacked cache comes back updated (see ``stage_apply``).  Given the
+    encoder's output, the layer writes its cross-attention K/V into the
+    cache; without it, it reads them from there."""
     cfg = ctx.cfg
+    mixer = MIXERS[ld.mixer]
     aux = jnp.zeros((), jnp.float32)
-    stacked = cache.get("self") if cache else None
-    in_place = ld.mixer == "attn"
-    self_cache = (stacked if in_place or stacked is None
-                  else _at(stacked, layer))
+    stacked = None if cache is None else cache["self"]
+    own = (stacked if mixer.stacked or stacked is None
+           else _at(stacked, layer))
     h = rmsnorm(params["norm1"], x, cfg.norm_eps)
-
     with jax.named_scope(ld.mixer):     # names it in the device trace
-        if ld.mixer == "attn":
-            out, new_self = attention.attn_apply(
-                params["mixer"], h, cfg, positions=ctx.positions,
-                causal=ctx.causal, window=ctx.window, cache=self_cache,
-                layer=layer, chunk=cfg.attn_chunk,
-                inner_unroll=ctx.inner_unroll)
-            out = attention.attn_out_project(params["mixer"], out)
-        elif ld.mixer == "mla":
-            out, new_self = mla.mla_apply(
-                params["mixer"], h, cfg, positions=ctx.positions,
-                window=ctx.window, cache=self_cache)
-        elif ld.mixer == "mamba":
-            out, new_self = mamba.mamba_apply(params["mixer"], h, cfg,
-                                              cache=self_cache,
-                                              unroll=ctx.inner_unroll)
-        elif ld.mixer == "mlstm":
-            out, new_self = xlstm.mlstm_apply(params["mixer"], h, cfg,
-                                              cache=self_cache)
-        elif ld.mixer == "slstm":
-            out, new_self = xlstm.slstm_apply(params["mixer"], h, cfg,
-                                              cache=self_cache)
-        else:
-            raise ValueError(ld.mixer)
+        out, new_self = mixer.apply(params["mixer"], h, ctx, own, layer)
     x = x + out
 
+    new_cache: Optional[dict] = None
+    if cache is not None:
+        new_cache = dict(cache, self=new_self if mixer.stacked
+                         else _put(stacked, new_self, layer))
+
     if ld.cross_attn:
-        assert ctx.enc_out is not None or (cache and "cross" in cache)
         hx = rmsnorm(params["norm_x"], x, cfg.norm_eps)
-        if cache and "cross" in cache and ctx.enc_out is None:
+        if ctx.enc_out is None:
             ckv = _at(cache["cross"], layer)
         else:
             ckv = attention.cross_kv(params["cross"], ctx.enc_out)
+            if cache is not None:
+                new_cache["cross"] = _put(cache["cross"], ckv, layer)
         x = x + attention.cross_attend(params["cross"], hx, ckv, cfg)
 
     if ld.ffn != "none":
@@ -192,13 +139,6 @@ def layer_apply(
                 y, aux = ffn.moe_apply(params["ffn"], h2, cfg,
                                        group_size=ctx.moe_group_size)
         x = x + y
-
-    new_cache: Optional[dict] = None
-    if cache is not None:
-        new_cache = dict(cache)     # the cross cache is read-only
-        if stacked is not None:
-            new_cache["self"] = (new_self if in_place
-                                 else _put(stacked, new_self, layer))
     return x, new_cache, aux
 
 
@@ -278,17 +218,21 @@ def stage_apply(
 ) -> tuple[jax.Array, Optional[dict], jax.Array]:
     """Scan over stage.repeats; inside, unroll the (short) pattern.
 
-    The caches ride in the scan's carry, stacked, so a step that donates
-    them updates them in place.  Scanned in and out as xs/ys they would need
-    a second buffer the size of every cache, and a copy of it into the
-    donated one on each step.  An ``attn`` layer writes its new token's K/V
-    row into the stacked cache and reads its K/V straight from it: taking
-    its slice out and writing it back would move the whole cache several
-    times a token for a one-row change.  The other mixers (``mla``,
-    ``mamba``, ``mlstm``, ``slstm``) take their layer's slice out and write
-    it back whole: the recurrent ones replace their whole state each step,
-    and an MLA cache holds one latent of a few hundred values a position,
-    a small share of an MHA cache's.  A cross-attention cache is only read.
+    The one scan of the forward pass (no caches), of a prompt's prefill
+    and of a decode step (both with caches).  The caches ride in the
+    scan's carry, stacked, so a step that donates them updates them in
+    place.  Scanned in and out as xs/ys they would need a second buffer the
+    size of every cache, and a copy of it into the donated one on each
+    step.  Each mixer writes its own cache (``Mixer.apply``).  A
+    ``stacked`` mixer (``attn``) writes its new positions' K/V into the
+    stacked cache and reads its K/V straight from it: taking its slice out
+    and writing it back would move the whole cache several times a token
+    for a one-row change.  The others (``mla``, ``mamba``, ``mlstm``,
+    ``slstm``) are given their layer's slice and written back whole: the
+    recurrent ones replace their whole state each step, and an MLA cache
+    holds one latent of a few hundred values a position, a small share of
+    an MHA cache's.  A cross-attention cache is written by the prefill
+    (which has the encoder's output) and only read by decode steps.
 
     ``unroll=True`` fully unrolls the repeat loop — used by the roofline
     analysis so cost_analysis counts every layer (XLA cost analysis counts a

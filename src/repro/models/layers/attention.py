@@ -2,22 +2,23 @@
 position-tracked (optionally rotating) KV cache.
 
 Cache layout: k/v [B, KV, D, S] with an int32 ``positions [B, S]`` slot map
-(-1 = empty).  Full causal caches write slot ``pos``; sliding-window caches
-write slot ``pos % window`` — the same attention code handles both because
+(-1 = empty).  ``common.write_positions`` writes it, for a prompt and for a
+decode step alike: full causal caches write slot ``pos``, sliding-window
+caches slot ``pos % S`` -- the same attention code handles both because
 masks are derived from the stored absolute positions, never from slot order.
 
-Decode works on the stage's stacked cache (k/v [L, B, KV, D, S],
-``positions`` [L, B, S]) and a layer index: it writes the new token's K/V
-into its slot in place and reads the layer's K/V straight out of the
-stacked buffers, so a step reads each layer's K/V once and writes one
-slot.  The other mixers take their layer's slice out and write it back
-whole (``blocks.stage_apply`` says why); for K/V that was a slice copy, a
-layout copy each way and the write-back, each over the whole layer, every
-token.  The slots are the minor axis because that is how the TPU lays out
-a cache whose head size is no multiple of 128 (so it pads none of it), and
-the slot write is held to that layout: left free, XLA copies the whole
-cache into a layout with the slot axis major before the layer scan, and
-back after it.
+Attention works on the stage's stacked cache (k/v [L, B, KV, D, S],
+``positions`` [L, B, S]) and a layer index: it writes the new positions'
+K/V into their slots in place, and a decode step reads the layer's K/V
+straight out of the stacked buffers, so a step reads each layer's K/V once
+and writes one slot.  The other mixers take their layer's slice out and
+write it back whole (``blocks.stage_apply`` says why); for K/V that was a
+slice copy, a layout copy each way and the write-back, each over the whole
+layer, every token.  The slots are the minor axis because that is how the
+TPU lays out a cache whose head size is no multiple of 128 (so it pads none
+of it), and the slot write is held to that layout: left free, XLA copies
+the whole cache into a layout with the slot axis major before the layer
+scan, and back after it.
 """
 from __future__ import annotations
 
@@ -28,7 +29,8 @@ import jax.numpy as jnp
 from jax.experimental.layout import Layout, with_layout_constraint
 
 from ..config import ModelConfig
-from .common import apply_rope, dense_init, masked_softmax, rope_cos_sin, zeros
+from .common import LayerCtx, Mixer, apply_rope, dense_init, masked_softmax, \
+    rope_cos_sin, write_positions, zeros
 
 
 # --------------------------------------------------------------------------- #
@@ -71,10 +73,11 @@ def attn_axes(cfg: ModelConfig) -> dict:
 # --------------------------------------------------------------------------- #
 
 
-def init_kv_cache(batch: int, length: int, n_kv: int, head_dim: int, dtype) -> dict:
+def init_kv_cache(batch: int, length: int, cfg: ModelConfig, dtype) -> dict:
+    shape = (batch, cfg.n_kv_heads, cfg.resolved_head_dim, length)
     return {
-        "k": jnp.zeros((batch, n_kv, head_dim, length), dtype=dtype),
-        "v": jnp.zeros((batch, n_kv, head_dim, length), dtype=dtype),
+        "k": jnp.zeros(shape, dtype=dtype),
+        "v": jnp.zeros(shape, dtype=dtype),
         "positions": jnp.full((batch, length), -1, dtype=jnp.int32),
     }
 
@@ -85,10 +88,6 @@ def kv_cache_axes() -> dict:
         "v": ("batch", "kv_heads", "head_dim", "cache"),
         "positions": ("batch", "cache"),
     }
-
-
-def _write_slot(cache_len: int, pos: jax.Array, window: int) -> jax.Array:
-    return jnp.where(window > 0, pos % cache_len, pos)
 
 
 # --------------------------------------------------------------------------- #
@@ -111,6 +110,8 @@ def _gqa_scores_to_out(q, k, v, mask, kv: str = "bskd") -> jax.Array:
 
 def causal_mask(q_pos: jax.Array, k_pos: jax.Array, window: int) -> jax.Array:
     """q_pos [T], k_pos [S] (absolute) -> [T, S] bool."""
+    # traced, so that a prompt's NumPy positions build no [T, S] constant
+    q_pos, k_pos = jnp.asarray(q_pos), jnp.asarray(k_pos)
     m = k_pos[None, :] <= q_pos[:, None]
     if window > 0:
         m &= k_pos[None, :] > q_pos[:, None] - window
@@ -144,72 +145,55 @@ def _chunked_attention(q, k, v, q_pos, k_pos, window: int, chunk: int,
 def attn_apply(
     params: dict,
     x: jax.Array,                       # [B, T, d]
-    cfg: ModelConfig,
-    *,
-    positions: jax.Array,               # [T] absolute positions
-    causal: bool = True,
-    window: int = 0,
-    cache: Optional[dict] = None,       # decode: the stage's stacked cache
-    layer: jax.Array | int = 0,         # decode: this layer's index in it
-    rope: bool = True,
-    chunk: int = 0,                     # blocked attention (0 = naive)
-    inner_unroll: int | bool = 1,
+    ctx: LayerCtx,
+    cache: Optional[dict] = None,       # the stage's stacked cache
+    layer: jax.Array | int = 0,         # this layer's index in it
 ) -> tuple[jax.Array, Optional[dict]]:
-    b, t, d = x.shape
+    cfg, positions, window = ctx.cfg, ctx.positions, ctx.window
+    t = x.shape[1]
     hd = cfg.resolved_head_dim
 
     q = jnp.einsum("btd,dhk->bthk", x, params["wq"])
     if "bq" in params:
         q = q + params["bq"]
-
-    if rope:
-        cos_q, sin_q = rope_cos_sin(positions, hd, cfg.rope_theta)
-        q = apply_rope(q, cos_q, sin_q)
+    cos, sin = rope_cos_sin(positions, hd, cfg.rope_theta)
+    q = apply_rope(q, cos, sin)
 
     k = jnp.einsum("bsd,dhk->bshk", x, params["wk"])
     v = jnp.einsum("bsd,dhk->bshk", x, params["wv"])
     if "bk" in params:
         k = k + params["bk"]
         v = v + params["bv"]
-    if rope:
-        cos_k, sin_k = rope_cos_sin(positions, hd, cfg.rope_theta)
-        k = apply_rope(k, cos_k, sin_k)
+    k = apply_rope(k, cos, sin)
 
-    if cache is None:
-        if causal and chunk and t > chunk:
-            return _chunked_attention(q, k, v, positions, positions, window,
-                                      chunk, inner_unroll), None
-        if causal:
-            mask = causal_mask(positions, positions, window)[None, None]
+    if cache is not None:
+        cache = write_positions(cache, {"k": jnp.moveaxis(k, 1, -1),
+                                        "v": jnp.moveaxis(v, 1, -1)},
+                                positions, window, -1, layer, fresh=t > 1)
+        cache = dict(cache, k=_row_major(cache["k"]),
+                     v=_row_major(cache["v"]))
+
+    if cache is None or t > 1:
+        chunk = cfg.attn_chunk
+        if ctx.causal and chunk and t > chunk:
+            out = _chunked_attention(q, k, v, positions, positions, window,
+                                     chunk, ctx.inner_unroll)
         else:
-            mask = jnp.ones((1, 1, t, t), dtype=bool)
-        return _gqa_scores_to_out(q, k, v, mask), None
+            mask = (causal_mask(positions, positions, window) if ctx.causal
+                    else jnp.ones((t, t), dtype=bool))
+            out = _gqa_scores_to_out(q, k, v, mask[None, None])
+        return attn_out_project(params, out), cache
 
-    # ---- decode (T == 1): write ``slot`` of layer ``layer``, read it ---- #
+    # ---- decode (T == 1): read layer ``layer``'s K/V where it lies ------ #
     pos = positions[-1]                               # scalar current position
-    slot = _write_slot(cache["k"].shape[-1], pos, window)
-    new_cache = {
-        "k": _row_major(jax.lax.dynamic_update_slice(
-            cache["k"], _as_slot(k), (layer, 0, 0, 0, slot))),
-        "v": _row_major(jax.lax.dynamic_update_slice(
-            cache["v"], _as_slot(v), (layer, 0, 0, 0, slot))),
-        "positions": jax.lax.dynamic_update_slice(
-            cache["positions"], jnp.full((1, b, 1), pos, jnp.int32),
-            (layer, 0, slot)),
-    }
-    k, v, stored = (jax.lax.dynamic_index_in_dim(new_cache[n], layer, 0, False)
+    k, v, stored = (jax.lax.dynamic_index_in_dim(cache[n], layer, 0, False)
                     for n in ("k", "v", "positions"))
     valid = (stored >= 0) & (stored <= pos)
     if window > 0:
         valid &= stored > pos - window
     mask = valid[:, None, None, :]                    # [B, 1, T=1, S]
     out = _gqa_scores_to_out(q, k, v, mask, kv="bkds")
-    return out, new_cache
-
-
-def _as_slot(kv: jax.Array) -> jax.Array:
-    """One position's [B, 1, KV, D] as the update of one stacked slot."""
-    return kv[None, :, 0, :, :, None]                 # [1, B, KV, D, 1]
+    return attn_out_project(params, out), cache
 
 
 def _row_major(x: jax.Array) -> jax.Array:
@@ -244,3 +228,7 @@ def cross_attend(params: dict, x: jax.Array, ckv: dict, cfg: ModelConfig) -> jax
     mask = jnp.ones((b, 1, t, ckv["k"].shape[1]), dtype=bool)
     out = _gqa_scores_to_out(q, ckv["k"], ckv["v"], mask)
     return attn_out_project(params, out)
+
+
+MIXER = Mixer(init=attn_init, axes=attn_axes, cache_init=init_kv_cache,
+              cache_axes=kv_cache_axes, apply=attn_apply, stacked=True)

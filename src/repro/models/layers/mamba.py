@@ -3,7 +3,8 @@
 
 Prefill/train path: chunked associative scan over time (chunk size bounds the
 [B, chunk, d_inner, d_state] working set — important for the 512-device
-dry-run of jamba at seq 4k/32k).  Decode path: single-step recurrence with a
+dry-run of jamba at seq 4k/32k); a prompt's cache is the scan's final state
+and the conv window's tail.  Decode path: single-step recurrence with a
 (conv window, ssm state) cache.
 """
 from __future__ import annotations
@@ -14,7 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from ..config import ModelConfig
-from .common import dense_init, normal_init, silu
+from .common import LayerCtx, Mixer, dense_init, normal_init, silu
 
 CHUNK = 512
 
@@ -54,7 +55,8 @@ def mamba_axes(cfg: ModelConfig) -> dict:
     }
 
 
-def init_mamba_cache(batch: int, cfg: ModelConfig, dtype) -> dict:
+def init_mamba_cache(batch: int, cache_len: int, cfg: ModelConfig,
+                     dtype) -> dict:
     mc = cfg.mamba
     di = cfg.mamba_d_inner
     return {
@@ -103,19 +105,19 @@ def _conv_causal(params: dict, x: jax.Array, prior: Optional[jax.Array]) -> jax.
 def mamba_apply(
     params: dict,
     x: jax.Array,                        # [B, T, d]
-    cfg: ModelConfig,
-    *,
+    ctx: LayerCtx,
     cache: Optional[dict] = None,
-    unroll: int | bool = 1,
+    layer: jax.Array | int = 0,
 ) -> tuple[jax.Array, Optional[dict]]:
+    cfg = ctx.cfg
     di = cfg.mamba_d_inner
+    b, t = x.shape[:2]
     xz = jnp.einsum("btd,de->bte", x, params["in_proj"])
     xi, z = xz[..., :di], xz[..., di:]
 
-    if cache is None:
+    if cache is None or t > 1:
         xc = silu(_conv_causal(params, xi, None))
         abar, bx, c = _ssm_terms(params, xc, cfg)             # [B,T,di,ds]
-        b, t = x.shape[:2]
 
         def chunk_step(h0, ab):
             a_ch, b_ch = ab                                    # [B,C,di,ds]
@@ -141,10 +143,16 @@ def mamba_apply(
         abar = abar.reshape(b, nch, CHUNK, di, -1).swapaxes(0, 1)
         bx = bx.reshape(b, nch, CHUNK, di, -1).swapaxes(0, 1)
         h0 = jnp.zeros((b, di, abar.shape[-1]), jnp.float32)
-        h_last, hs = jax.lax.scan(chunk_step, h0, (abar, bx), unroll=unroll)
+        h_last, hs = jax.lax.scan(chunk_step, h0, (abar, bx),
+                                  unroll=ctx.inner_unroll)
         hs = hs.swapaxes(0, 1).reshape(b, nch * CHUNK, di, -1)[:, :t]
         y = jnp.einsum("btis,bts->bti", hs, c)
         new_cache = None
+        if cache is not None:       # the chunk pads leave h_last as it was
+            k = params["conv_w"].shape[0]
+            conv = jnp.pad(xi, [(0, 0), (k - 1, 0), (0, 0)])[:, t:]
+            new_cache = {"conv": conv.astype(cache["conv"].dtype),
+                         "ssm": h_last}
     else:
         # decode: T == 1
         conv_win = jnp.concatenate([cache["conv"], xi], axis=1)
@@ -160,3 +168,7 @@ def mamba_apply(
     y = y.astype(x.dtype) + params["d_skip"] * xi
     y = y * silu(z)
     return jnp.einsum("bti,id->btd", y, params["out_proj"]), new_cache
+
+
+MIXER = Mixer(init=mamba_init, axes=mamba_axes, cache_init=init_mamba_cache,
+              cache_axes=mamba_cache_axes, apply=mamba_apply)

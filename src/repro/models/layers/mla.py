@@ -6,7 +6,9 @@ cache is ~(kv_rank + rope_dim) per token instead of 2 * H * head_dim).
 
 Prefill uses the naive (expanded) form.  Decode uses the *absorbed* form:
 W_uk is folded into the query and W_uv into the output projection, so
-attention runs directly against the compressed cache.
+attention runs directly against the compressed cache.  Both write the
+latents with ``common.write_positions``, the slot rule attention's K/V
+follows.
 """
 from __future__ import annotations
 
@@ -16,8 +18,8 @@ import jax
 import jax.numpy as jnp
 
 from ..config import ModelConfig
-from .common import apply_rope, dense_init, masked_softmax, rmsnorm, rmsnorm_axes, \
-    rmsnorm_init, rope_cos_sin
+from .common import LayerCtx, Mixer, apply_rope, dense_init, masked_softmax, \
+    rmsnorm, rmsnorm_axes, rmsnorm_init, rope_cos_sin, write_positions
 from .attention import causal_mask
 
 
@@ -105,21 +107,23 @@ def _compress(params: dict, x: jax.Array, cfg: ModelConfig, positions) -> tuple:
 def mla_apply(
     params: dict,
     x: jax.Array,
-    cfg: ModelConfig,
-    *,
-    positions: jax.Array,
-    window: int = 0,
+    ctx: LayerCtx,
     cache: Optional[dict] = None,
+    layer: jax.Array | int = 0,
 ) -> tuple[jax.Array, Optional[dict]]:
+    cfg, positions, window = ctx.cfg, ctx.positions, ctx.window
     m = cfg.mla
     b, t, _ = x.shape
     h = cfg.n_heads
     scale = jnp.asarray(m.nope_head_dim + m.rope_head_dim, jnp.float32) ** -0.5
     q_nope, q_rope = _queries(params, x, cfg, positions)
+    c_kv, k_rope = _compress(params, x, cfg, positions)
+    if cache is not None:
+        cache = write_positions(cache, {"c_kv": c_kv, "k_rope": k_rope},
+                                positions, window, 1, fresh=t > 1)
 
-    if cache is None:
+    if cache is None or t > 1:
         # ---- naive (expanded) prefill form ------------------------------- #
-        c_kv, k_rope = _compress(params, x, cfg, positions)
         k_nope = jnp.einsum("bsr,rhk->bshk", c_kv, params["wuk"])
         v = jnp.einsum("bsr,rhk->bshk", c_kv, params["wuv"])
         scores = (
@@ -131,23 +135,11 @@ def mla_apply(
         out = jnp.einsum("bhts,bshk->bthk", w.astype(v.dtype), v)
         y = jnp.einsum("bte,ed->btd", out.reshape(b, t, h * m.v_head_dim),
                        params["wo"])
-        return y, None
+        return y, cache
 
     # ---- absorbed decode form (T == 1) ----------------------------------- #
     pos = positions[-1]
-    cache_len = cache["c_kv"].shape[1]
-    c_new, kr_new = _compress(params, x, cfg, positions)
-    slot = jnp.where(window > 0, pos % cache_len, pos)
-    new_cache = {
-        "c_kv": jax.lax.dynamic_update_slice_in_dim(cache["c_kv"], c_new, slot, 1),
-        "k_rope": jax.lax.dynamic_update_slice_in_dim(cache["k_rope"], kr_new, slot, 1),
-        "positions": jax.lax.dynamic_update_slice_in_dim(
-            cache["positions"], jnp.broadcast_to(pos, (b, 1)).astype(jnp.int32),
-            slot, 1),
-    }
-    c_kv, k_rope, stored = (
-        new_cache["c_kv"], new_cache["k_rope"], new_cache["positions"]
-    )
+    c_kv, k_rope, stored = cache["c_kv"], cache["k_rope"], cache["positions"]
     # Absorb W_uk into q: q_abs [B,T,H,R]
     q_abs = jnp.einsum("bthk,rhk->bthr", q_nope, params["wuk"])
     scores = (
@@ -158,7 +150,11 @@ def mla_apply(
     if window > 0:
         valid &= stored > pos - window
     w = masked_softmax(scores, valid[:, None, None, :])
-    ctx = jnp.einsum("bhts,bsr->bthr", w.astype(c_kv.dtype), c_kv)  # [B,1,H,R]
-    out = jnp.einsum("bthr,rhk->bthk", ctx, params["wuv"])
+    lat = jnp.einsum("bhts,bsr->bthr", w.astype(c_kv.dtype), c_kv)  # [B,1,H,R]
+    out = jnp.einsum("bthr,rhk->bthk", lat, params["wuv"])
     y = jnp.einsum("bte,ed->btd", out.reshape(b, t, h * m.v_head_dim), params["wo"])
-    return y, new_cache
+    return y, cache
+
+
+MIXER = Mixer(init=mla_init, axes=mla_axes, cache_init=init_mla_cache,
+              cache_axes=mla_cache_axes, apply=mla_apply)

@@ -3,9 +3,10 @@ and sLSTM (scalar memory, sequential scan), both with exponential gating and
 the max-state stabiliser.
 
 The mLSTM has two mathematically equivalent forms:
-  * parallel (training/prefill): an attention-like T x T decay-masked form;
+  * parallel (training/prefill): an attention-like T x T decay-masked form
+    (or its chunkwise form, whose carry is a prompt's cache);
   * recurrent (decode): C_t = f'_t C_{t-1} + i'_t v_t k_t^T.
-A property test asserts the two agree (tests/test_xlstm_equivalence.py).
+A property test asserts the two agree (tests/test_layers_equivalence.py).
 """
 from __future__ import annotations
 
@@ -15,7 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from ..config import ModelConfig
-from .common import dense_init, groupnorm_heads, silu
+from .common import LayerCtx, Mixer, dense_init, groupnorm_heads, silu
 
 
 # =========================================================================== #
@@ -64,7 +65,8 @@ def mlstm_axes(cfg: ModelConfig) -> dict:
     }
 
 
-def init_mlstm_cache(batch: int, cfg: ModelConfig, dtype) -> dict:
+def init_mlstm_cache(batch: int, cache_len: int, cfg: ModelConfig,
+                     dtype) -> dict:
     xc = cfg.xlstm
     di = int(xc.proj_factor_mlstm * cfg.d_model)
     h = cfg.n_heads
@@ -117,11 +119,11 @@ def _mlstm_chunked(q, k, v, i_pre, f_pre, chunk: int,
     """
     b, t, h, dh = q.shape
     n_pad = (-t) % chunk
-    if n_pad:
+    if n_pad:   # pads neither forget nor take in: the final state is T's
         pad = [(0, 0), (0, n_pad), (0, 0)]
         q, k, v = (jnp.pad(a, pad + [(0, 0)]) for a in (q, k, v))
-        i_pre = jnp.pad(i_pre, pad)
-        f_pre = jnp.pad(f_pre, pad)
+        i_pre = jnp.pad(i_pre, pad, constant_values=-1e30)
+        f_pre = jnp.pad(f_pre, pad, constant_values=1e30)
     tp = t + n_pad
     nb = tp // chunk
     scale = jnp.asarray(dh, jnp.float32) ** -0.5
@@ -181,43 +183,81 @@ def _mlstm_chunked(q, k, v, i_pre, f_pre, chunk: int,
     return hs, ms, final_state
 
 
+def _mlstm_state(k, v, i_pre, f_pre) -> tuple:
+    """The recurrent (C, n, m) state after k/v [B,T,H,K], i/f [B,T,H],
+    from an empty one: what the one-block parallel form leaves no carry
+    for."""
+
+    def step(state, inp):
+        c, n, m = state
+        kt, vt, it, ft = inp
+        logf = jax.nn.log_sigmoid(ft)
+        m_new = jnp.maximum(logf + m, it)
+        f_eff = jnp.exp(logf + m - m_new)
+        i_eff = jnp.exp(it - m_new)
+        c = f_eff[..., None, None] * c + i_eff[..., None, None] * \
+            kt[..., :, None] * vt[..., None, :]
+        n = f_eff[..., None] * n + i_eff[..., None] * kt
+        return (c, n, m_new), None
+
+    b, t, hh, dh = k.shape
+    state0 = (jnp.zeros((b, hh, dh, dh), jnp.float32),
+              jnp.zeros((b, hh, dh), jnp.float32),
+              jnp.full((b, hh), -1e30, jnp.float32))
+    state, _ = jax.lax.scan(
+        step, state0,
+        (k.swapaxes(0, 1).astype(jnp.float32),
+         v.swapaxes(0, 1).astype(jnp.float32),
+         i_pre.swapaxes(0, 1), f_pre.swapaxes(0, 1)))
+    return state
+
+
 def mlstm_apply(
     params: dict,
     x: jax.Array,
-    cfg: ModelConfig,
-    *,
+    ctx: LayerCtx,
     cache: Optional[dict] = None,
+    layer: jax.Array | int = 0,
 ) -> tuple[jax.Array, Optional[dict]]:
+    cfg = ctx.cfg
+    b, t = x.shape[:2]
     di = params["skip"].shape[0]
     up = jnp.einsum("btd,de->bte", x, params["up_proj"])
     xi_raw, z = up[..., :di], up[..., di:]
 
-    if cache is None and cfg.mlstm_chunk and x.shape[1] > cfg.mlstm_chunk:
+    if cache is None or t > 1:
         xi = silu(_conv_causal(params["conv_w"], params["conv_b"], xi_raw, None))
         q, k, v, i_pre, f_pre = _qkv_gates(params, xi)
-        hout, _, _ = _mlstm_chunked(q, k, v, i_pre, f_pre, cfg.mlstm_chunk)
+        if cfg.mlstm_chunk and t > cfg.mlstm_chunk:
+            hout, _, state = _mlstm_chunked(q, k, v, i_pre, f_pre,
+                                            cfg.mlstm_chunk)
+        else:
+            dh = q.shape[-1]
+            logf = jax.nn.log_sigmoid(f_pre)                  # [B,T,H]
+            cum = jnp.cumsum(logf, axis=1)
+            # a[t, s] = sum_{j=s+1..t} logf_j + logi_s  (t >= s)
+            amat = (cum[:, :, None, :] - cum[:, None, :, :]
+                    + i_pre[:, None, :, :])                   # [B,Tq,Ts,H]
+            causal = jnp.tril(jnp.ones((t, t), bool))[None, :, :, None]
+            amat = jnp.where(causal, amat, -jnp.inf)
+            m = jnp.max(amat, axis=2, keepdims=True)          # [B,T,1,H]
+            dmat = jnp.exp(amat - m)                           # stabilised
+            scale = jnp.asarray(dh, jnp.float32) ** -0.5
+            scores = jnp.einsum("bthk,bshk->btsh", q.astype(jnp.float32),
+                                k.astype(jnp.float32)) * scale
+            sd = scores * dmat
+            norm = jnp.maximum(jnp.abs(sd.sum(axis=2)), jnp.exp(-m[:, :, 0]))
+            hout = jnp.einsum("btsh,bshk->bthk", sd, v.astype(jnp.float32))
+            hout = hout / (norm[..., None] + 1e-6)
+            if cache is not None:
+                state = _mlstm_state(k, v, i_pre, f_pre)
         new_cache = None
-    elif cache is None:
-        xi = silu(_conv_causal(params["conv_w"], params["conv_b"], xi_raw, None))
-        q, k, v, i_pre, f_pre = _qkv_gates(params, xi)
-        b, t, h, dh = q.shape
-        logf = jax.nn.log_sigmoid(f_pre)                      # [B,T,H]
-        cum = jnp.cumsum(logf, axis=1)
-        # a[t, s] = sum_{j=s+1..t} logf_j + logi_s  (t >= s)
-        amat = cum[:, :, None, :] - cum[:, None, :, :] + i_pre[:, None, :, :]
-        # [B, Tq, Ts, H]
-        causal = jnp.tril(jnp.ones((t, t), bool))[None, :, :, None]
-        amat = jnp.where(causal, amat, -jnp.inf)
-        m = jnp.max(amat, axis=2, keepdims=True)              # [B,T,1,H]
-        dmat = jnp.exp(amat - m)                               # stabilised
-        scale = jnp.asarray(dh, jnp.float32) ** -0.5
-        scores = jnp.einsum("bthk,bshk->btsh", q.astype(jnp.float32),
-                            k.astype(jnp.float32)) * scale
-        sd = scores * dmat
-        norm = jnp.maximum(jnp.abs(sd.sum(axis=2)), jnp.exp(-m[:, :, 0]))
-        hout = jnp.einsum("btsh,bshk->bthk", sd, v.astype(jnp.float32))
-        hout = hout / (norm[..., None] + 1e-6)
-        new_cache = None
+        if cache is not None:
+            kk = params["conv_w"].shape[0]
+            conv = jnp.pad(xi_raw, [(0, 0), (kk - 1, 0), (0, 0)])[:, t:]
+            c, n, m_state = state
+            new_cache = {"conv": conv.astype(cache["conv"].dtype), "c": c,
+                         "n": n, "m": m_state}
     else:
         conv_win = jnp.concatenate([cache["conv"], xi_raw], axis=1)
         xi = silu(
@@ -225,7 +265,7 @@ def mlstm_apply(
             + params["conv_b"]
         )[:, None, :]
         q, k, v, i_pre, f_pre = _qkv_gates(params, xi)
-        b, _, h, dh = q.shape
+        dh = q.shape[-1]
         logf = jax.nn.log_sigmoid(f_pre[:, 0])                # [B,H]
         logi = i_pre[:, 0]
         m_new = jnp.maximum(logf + cache["m"], logi)
@@ -248,10 +288,13 @@ def mlstm_apply(
         new_cache = {"conv": conv_win[:, 1:], "c": c_new, "n": n_new, "m": m_new}
 
     hout = groupnorm_heads(hout).astype(x.dtype)
-    b, t = x.shape[:2]
     hflat = hout.reshape(b, t, di) + params["skip"] * xi
     y = hflat * silu(z)
     return jnp.einsum("bti,id->btd", y, params["down_proj"]), new_cache
+
+
+MLSTM = Mixer(init=mlstm_init, axes=mlstm_axes, cache_init=init_mlstm_cache,
+              cache_axes=mlstm_cache_axes, apply=mlstm_apply)
 
 
 # =========================================================================== #
@@ -288,7 +331,8 @@ def slstm_axes(cfg: ModelConfig) -> dict:
     }
 
 
-def init_slstm_cache(batch: int, cfg: ModelConfig, dtype) -> dict:
+def init_slstm_cache(batch: int, cache_len: int, cfg: ModelConfig,
+                     dtype) -> dict:
     h = cfg.n_heads
     dh = cfg.d_model // h
     return {
@@ -329,16 +373,16 @@ def _slstm_step(params, state, wx_t):
 def slstm_apply(
     params: dict,
     x: jax.Array,
-    cfg: ModelConfig,
-    *,
+    ctx: LayerCtx,
     cache: Optional[dict] = None,
+    layer: jax.Array | int = 0,
 ) -> tuple[jax.Array, Optional[dict]]:
     b, t, d = x.shape
-    h_heads = cfg.n_heads
+    h_heads = ctx.cfg.n_heads
     dh = d // h_heads
     wx = jnp.einsum("btd,dghk->btghk", x, params["w"])        # [B,T,4,H,dh]
 
-    if cache is None:
+    if cache is None or t > 1:
         state = (
             jnp.zeros((b, h_heads, dh), jnp.float32),
             jnp.zeros((b, h_heads, dh), jnp.float32),
@@ -350,14 +394,13 @@ def slstm_apply(
             new = _slstm_step(params, state, wx_t)
             return new, new[0]
 
-        _, hs = jax.lax.scan(step, state, wx.swapaxes(0, 1))
+        state, hs = jax.lax.scan(step, state, wx.swapaxes(0, 1))
         hs = hs.swapaxes(0, 1)                                 # [B,T,H,dh]
-        new_cache = None
     else:
-        state = (cache["h"], cache["c"], cache["n"], cache["m"])
-        new = _slstm_step(params, state, wx[:, 0])
-        hs = new[0][:, None]
-        new_cache = {"h": new[0], "c": new[1], "n": new[2], "m": new[3]}
+        state = _slstm_step(params, (cache["h"], cache["c"], cache["n"],
+                                     cache["m"]), wx[:, 0])
+        hs = state[0][:, None]
+    new_cache = None if cache is None else dict(zip("hcnm", state))
 
     hs = groupnorm_heads(hs).astype(x.dtype).reshape(b, t, d)
     y = x + hs                                                 # residual core
@@ -366,3 +409,7 @@ def slstm_apply(
     df = gu.shape[-1] // 2
     y2 = silu(gu[..., :df]) * gu[..., df:]
     return jnp.einsum("btf,fd->btd", y2, params["ffn_down"]), new_cache
+
+
+SLSTM = Mixer(init=slstm_init, axes=slstm_axes, cache_init=init_slstm_cache,
+              cache_axes=slstm_cache_axes, apply=slstm_apply)

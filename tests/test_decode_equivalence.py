@@ -5,7 +5,9 @@ from dataclasses import replace
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
+from numpy.testing import assert_allclose
 
 from repro.configs import ARCH_IDS, get_smoke_config
 from repro.models import model as M
@@ -110,3 +112,64 @@ def test_sliding_window_decode_matches_windowed_forward(prompt, steps):
             params, cfg, caches, tokens[:, i:i + 1], jnp.int32(i))
         err = float(jnp.abs(dec_logits[:, 0] - full_logits[:, i]).max())
         assert err < TOL, f"windowed step at {i}: {err}"
+
+
+def _windowed(cfg):
+    return replace(cfg, sliding_window=8)
+
+
+def _mlstm_chunked(cfg):
+    """mLSTM in chunks of 5: a 12-token prompt ends in a padded chunk."""
+    return replace(cfg, mlstm_chunk=5)
+
+
+K = 5    # prompt of the decoded side: K prefilled, T - K decoded
+
+
+@pytest.mark.parametrize("arch,widen,t,k,cache_len", [
+    *[(a, None, T, K, 64) for a in ARCH_IDS],
+    # 16 positions through an 8-slot ring: a prefill that wraps it, against
+    # 4 prefilled and 12 decoded one slot at a time; 19 leave the ring
+    # rotated (position 11 in slot 3), as a long prompt leaves Phi-3's
+    ("smollm-135m", _windowed, 16, 4, 8),
+    ("deepseek-v2-236b", _windowed, 16, 4, 8),
+    ("smollm-135m", _windowed, 19, 4, 8),
+    ("xlstm-1.3b", _mlstm_chunked, T, K, 64),
+], ids=[*ARCH_IDS, "attn-ring", "mla-ring", "attn-ring-rotated",
+        "mlstm-chunked"])
+def test_prefill_cache_equals_decoded_cache(arch, widen, t, k, cache_len):
+    """A prompt's prefill leaves the caches that prefilling its first k
+    tokens and decoding the rest leaves: each mixer's parallel pass writes
+    the same cache as its decode steps, slots and state alike."""
+    cfg = _uncap(get_smoke_config(arch))
+    if widen is not None:
+        cfg = widen(cfg)
+    params = M.init_params(cfg, jax.random.PRNGKey(0))
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, t), 0,
+                                cfg.vocab_size)
+    extra, pos_off = {}, 0
+    if cfg.modality_embed_dim:
+        n_mod = cfg.n_modality_tokens or T
+        extra["modality_emb"] = jax.random.normal(
+            jax.random.PRNGKey(9), (2, n_mod, cfg.modality_embed_dim))
+        if not cfg.is_encoder_decoder:
+            pos_off = n_mod
+
+    _, whole = M.prefill(params, cfg, {"tokens": tokens, **extra}, cache_len)
+    _, stepped = M.prefill(params, cfg, {"tokens": tokens[:, :k], **extra},
+                           cache_len)
+    for i in range(k, t):
+        _, stepped = M.decode_step(params, cfg, stepped, tokens[:, i:i + 1],
+                                   jnp.int32(pos_off + i))
+
+    got = jax.tree_util.tree_leaves_with_path(whole)
+    want = jax.tree_util.tree_leaves(stepped)
+    assert len(got) == len(want)
+    for (path, a), b in zip(got, want):
+        name = jax.tree_util.keystr(path)
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        if name.endswith("['positions']"):
+            assert (a == b).all(), name
+        else:
+            assert_allclose(np.asarray(a), np.asarray(b), atol=TOL,
+                            rtol=TOL, err_msg=name)

@@ -11,17 +11,23 @@ from repro.models.layers import mamba as Mb
 from repro.models.layers import mla as L
 from repro.models.layers import xlstm as X
 from repro.models.layers import attention as A
+from repro.models.layers.common import LayerCtx
+
+
+def _ctx(cfg, start, t=1):
+    """The context of positions start..start+t-1."""
+    return LayerCtx(cfg, jnp.arange(start, start + t))
 
 
 def test_mlstm_parallel_equals_recurrent():
     cfg = get_smoke_config("xlstm-1.3b")
     p = X.mlstm_init(jax.random.PRNGKey(0), cfg, jnp.float32)
     x = 0.5 * jax.random.normal(jax.random.PRNGKey(1), (2, 11, cfg.d_model))
-    y_par, _ = X.mlstm_apply(p, x, cfg)
-    cache = X.init_mlstm_cache(2, cfg, jnp.float32)
+    y_par, _ = X.mlstm_apply(p, x, _ctx(cfg, 0, 11))
+    cache = X.init_mlstm_cache(2, 0, cfg, jnp.float32)
     outs = []
     for t in range(11):
-        y, cache = X.mlstm_apply(p, x[:, t:t + 1], cfg, cache=cache)
+        y, cache = X.mlstm_apply(p, x[:, t:t + 1], _ctx(cfg, t), cache=cache)
         outs.append(y)
     y_rec = jnp.concatenate(outs, 1)
     assert_allclose(np.asarray(y_par), np.asarray(y_rec), atol=2e-5,
@@ -32,11 +38,11 @@ def test_slstm_scan_equals_step():
     cfg = get_smoke_config("xlstm-1.3b")
     p = X.slstm_init(jax.random.PRNGKey(0), cfg, jnp.float32)
     x = 0.5 * jax.random.normal(jax.random.PRNGKey(1), (2, 9, cfg.d_model))
-    y_scan, _ = X.slstm_apply(p, x, cfg)
-    cache = X.init_slstm_cache(2, cfg, jnp.float32)
+    y_scan, _ = X.slstm_apply(p, x, _ctx(cfg, 0, 9))
+    cache = X.init_slstm_cache(2, 0, cfg, jnp.float32)
     outs = []
     for t in range(9):
-        y, cache = X.slstm_apply(p, x[:, t:t + 1], cfg, cache=cache)
+        y, cache = X.slstm_apply(p, x[:, t:t + 1], _ctx(cfg, t), cache=cache)
         outs.append(y)
     assert_allclose(np.asarray(y_scan), np.asarray(jnp.concatenate(outs, 1)),
                     atol=2e-5, rtol=2e-4)
@@ -49,11 +55,11 @@ def test_mamba_chunked_scan_equals_step(t, monkeypatch):
     cfg = get_smoke_config("jamba-1.5-large-398b")
     p = Mb.mamba_init(jax.random.PRNGKey(0), cfg, jnp.float32)
     x = jax.random.normal(jax.random.PRNGKey(1), (2, t, cfg.d_model))
-    y_par, _ = Mb.mamba_apply(p, x, cfg)
-    cache = Mb.init_mamba_cache(2, cfg, jnp.float32)
+    y_par, _ = Mb.mamba_apply(p, x, _ctx(cfg, 0, t))
+    cache = Mb.init_mamba_cache(2, 0, cfg, jnp.float32)
     outs = []
     for i in range(t):
-        y, cache = Mb.mamba_apply(p, x[:, i:i + 1], cfg, cache=cache)
+        y, cache = Mb.mamba_apply(p, x[:, i:i + 1], _ctx(cfg, i), cache=cache)
         outs.append(y)
     assert_allclose(np.asarray(y_par), np.asarray(jnp.concatenate(outs, 1)),
                     atol=3e-5, rtol=3e-4)
@@ -66,12 +72,11 @@ def test_mla_absorbed_decode_equals_naive(qlora):
     cfg = replace(cfg, mla=replace(cfg.mla, q_lora_rank=qlora))
     p = L.mla_init(jax.random.PRNGKey(0), cfg, jnp.float32)
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 9, cfg.d_model))
-    y_naive, _ = L.mla_apply(p, x, cfg, positions=jnp.arange(9))
+    y_naive, _ = L.mla_apply(p, x, _ctx(cfg, 0, 9))
     cache = L.init_mla_cache(2, 16, cfg, jnp.float32)
     outs = []
     for t in range(9):
-        y, cache = L.mla_apply(p, x[:, t:t + 1], cfg,
-                               positions=jnp.asarray([t]), cache=cache)
+        y, cache = L.mla_apply(p, x[:, t:t + 1], _ctx(cfg, t), cache=cache)
         outs.append(y)
     assert_allclose(np.asarray(y_naive), np.asarray(jnp.concatenate(outs, 1)),
                     atol=2e-5, rtol=2e-4)
@@ -92,8 +97,8 @@ def test_gqa_attention_window_equals_full_when_window_large():
     p = A.attn_init(jax.random.PRNGKey(0), cfg, jnp.float32)
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 8, cfg.d_model))
     pos = jnp.arange(8)
-    y_full, _ = A.attn_apply(p, x, cfg, positions=pos, window=0)
-    y_win, _ = A.attn_apply(p, x, cfg, positions=pos, window=100)
+    y_full, _ = A.attn_apply(p, x, LayerCtx(cfg, pos, window=0))
+    y_win, _ = A.attn_apply(p, x, LayerCtx(cfg, pos, window=100))
     assert_allclose(np.asarray(y_full), np.asarray(y_win), atol=1e-6)
 
 
@@ -105,7 +110,8 @@ def test_mlstm_chunked_equals_naive(chunk, t):
     cfg = get_smoke_config("xlstm-1.3b")
     p = X.mlstm_init(jax.random.PRNGKey(0), cfg, jnp.float32)
     x = 0.5 * jax.random.normal(jax.random.PRNGKey(1), (2, t, cfg.d_model))
-    y_naive, _ = X.mlstm_apply(p, x, cfg)
-    y_chunk, _ = X.mlstm_apply(p, x, replace(cfg, mlstm_chunk=chunk))
+    y_naive, _ = X.mlstm_apply(p, x, _ctx(cfg, 0, t))
+    y_chunk, _ = X.mlstm_apply(p, x, _ctx(replace(cfg, mlstm_chunk=chunk), 0,
+                                          t))
     assert_allclose(np.asarray(y_naive), np.asarray(y_chunk), atol=2e-5,
                     rtol=2e-4)

@@ -206,3 +206,38 @@ def test_serve_step_moves_one_kv_row_a_layer_on_v5e(name, served_step):
               + nbytes([head, params["final_norm"]]))
     accessed = compiled.cost_analysis()["bytes accessed"]
     assert accessed < 1.1 * needed, (accessed, needed)
+
+
+def _slots_written(hlo: str):
+    """(dims, slots) for each chain of dynamic-update-slices of the
+    optimised HLO, fused or not, each writing into the one before: the
+    buffer's dims and how many slots (last axis) the chain writes."""
+    dims = {m.group(1): [int(n) for n in m.group(2).split(",") if n]
+            for m in re.finditer(r"(%[\w.\-]+) = \w+\[([\d,]*)\]", hlo)}
+    into = {m.group(1): (m.group(2), dims[m.group(3)][-1]) for m in re.finditer(
+        r"(%[\w.\-]+) = \w+\[[\d,]*\]\S* dynamic-update-slice\("
+        r"(%[\w.\-]+), (%[\w.\-]+)", hlo)}
+    out = []
+    for last in set(into) - {prev for prev, _ in into.values()}:
+        name, slots = last, 0
+        while name in into:
+            name, n = into[name]
+            slots += n
+        out.append((dims[last], slots))
+    return out
+
+
+def test_qwen2_prefill_writes_every_slot_of_each_layer_on_v5e(one_chip):
+    """A prompt shorter than the cache writes every slot of each layer's
+    K/V and positions map, the slots past it as empty.  The TPU compiler
+    drops the constant fill of the fresh caches that the layer scan
+    carries and writes at each layer's index, and starts the scan from an
+    unwritten buffer: a prefill that wrote its prompt's slots alone left
+    the others to garbage, which decode read as valid positions."""
+    cfg = get_config("qwen2-0.5b")
+    fn, args = _step_args(cfg, "prefill", one_chip)
+    chains = _slots_written(jax.jit(fn).lower(*args).compile().as_text())
+    for leaf in jax.tree.leaves(M.abstract_caches(cfg, 1, CACHE_LEN)):
+        writes = [n for d, n in chains if d == list(leaf.shape)]
+        assert writes and all(n == CACHE_LEN for n in writes), (leaf.shape,
+                                                                writes)
